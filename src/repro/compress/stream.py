@@ -127,7 +127,7 @@ def nonzero_bitmap(x: jax.Array, bs: int, bc: int) -> jax.Array:
 
 
 def compress(x: jax.Array, bitmap: jax.Array | None = None, *, bs: int = 8,
-             bc: int = 128, use_kernel: bool = True, interpret: bool = True,
+             bc: int = 128, use_kernel: bool = True,
              checksum: bool = False) -> CompressedMap:
     """(..., K) map -> CompressedMap. Leading dims flatten onto M. With no
     bitmap the nonzero-block bitmap is used (always lossless).
@@ -139,8 +139,7 @@ def compress(x: jax.Array, bitmap: jax.Array | None = None, *, bs: int = 8,
     if bitmap is None:
         bitmap = nonzero_bitmap(x2, bs, bc)
     if use_kernel:
-        payload, n_live = zebra_pack(x2, bitmap, bs=bs, bc=bc,
-                                     interpret=interpret)
+        payload, n_live = zebra_pack(x2, bitmap, bs=bs, bc=bc)
     else:
         payload, n_live = ref.zebra_pack_ref(x2, bitmap, bs, bc)
     csum = None
@@ -152,20 +151,17 @@ def compress(x: jax.Array, bitmap: jax.Array | None = None, *, bs: int = 8,
                          checksum=csum)
 
 
-def decompress(cm: CompressedMap, *, use_kernel: bool = True,
-               interpret: bool = True) -> jax.Array:
+def decompress(cm: CompressedMap, *, use_kernel: bool = True) -> jax.Array:
     bitmap = unpack_bitmap(cm.index, cm.m // cm.bs, cm.k // cm.bc)
     if use_kernel:
-        x2 = zebra_unpack(cm.payload, bitmap, bs=cm.bs, bc=cm.bc,
-                          interpret=interpret)
+        x2 = zebra_unpack(cm.payload, bitmap, bs=cm.bs, bc=cm.bc)
     else:
         x2 = ref.zebra_unpack_ref(cm.payload, bitmap, cm.bs, cm.bc)
     return x2.reshape(cm.shape)
 
 
 def compress_masked(x: jax.Array, t_obj: float, *, bs: int = 8, bc: int = 128,
-                    interpret: bool = True, checksum: bool = False
-                    ) -> CompressedMap:
+                    checksum: bool = False) -> CompressedMap:
     """Streaming lossy codec entry: raw (..., K) map -> Zebra-thresholded
     CompressedMap via the two-phase parallel producer (``zebra_mask_pack``)
     — the dense masked map is never materialized on the way into the
@@ -173,8 +169,7 @@ def compress_masked(x: jax.Array, t_obj: float, *, bs: int = 8, bc: int = 128,
     shape = tuple(x.shape)
     x2 = x.reshape(-1, shape[-1])
     M, K = x2.shape
-    payload, bitmap, n_live = zebra_mask_pack(x2, t_obj=t_obj, bs=bs, bc=bc,
-                                              interpret=interpret)
+    payload, bitmap, n_live = zebra_mask_pack(x2, t_obj=t_obj, bs=bs, bc=bc)
     csum = None
     if checksum:
         from .integrity import stream_checksum
@@ -184,8 +179,8 @@ def compress_masked(x: jax.Array, t_obj: float, *, bs: int = 8, bc: int = 128,
                          checksum=csum)
 
 
-def transport_tokens(x: jax.Array, t_obj: float, *, bs: int = 8, bc: int = 128,
-                     interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+def transport_tokens(x: jax.Array, t_obj: float, *, bs: int = 8, bc: int = 128
+                     ) -> tuple[jax.Array, jax.Array]:
     """The full inference-site round trip in streaming form:
     ``zebra_mask_pack`` -> ``zebra_unpack`` — only the (payload, bitmap)
     stream between producer and expander. Returns (masked map, keep
@@ -194,9 +189,8 @@ def transport_tokens(x: jax.Array, t_obj: float, *, bs: int = 8, bc: int = 128,
     moves compressed bytes when use_kernel is on."""
     shape = tuple(x.shape)
     x2 = x.reshape(-1, shape[-1])
-    payload, bitmap, _ = zebra_mask_pack(x2, t_obj=t_obj, bs=bs, bc=bc,
-                                         interpret=interpret)
-    y2 = zebra_unpack(payload, bitmap, bs=bs, bc=bc, interpret=interpret)
+    payload, bitmap, _ = zebra_mask_pack(x2, t_obj=t_obj, bs=bs, bc=bc)
+    y2 = zebra_unpack(payload, bitmap, bs=bs, bc=bc)
     return y2.reshape(shape), bitmap
 
 
@@ -210,8 +204,7 @@ def _path_str(path) -> str:
 
 
 def compress_tree(tree: Any, *, bs: int = 8, bc: int = 128,
-                  use_kernel: bool = True, interpret: bool = True,
-                  meter=None, site: str = "acts",
+                  use_kernel: bool = True, meter=None, site: str = "acts",
                   checksum: bool = False) -> Any:
     """Compress every compatible floating leaf of a pytree (lossless,
     nonzero-block bitmap); incompatible leaves pass through dense. Each leaf
@@ -234,7 +227,7 @@ def compress_tree(tree: Any, *, bs: int = 8, bc: int = 128,
                                    jnp.dtype(leaf.dtype).itemsize)
             return leaf
         cm = compress(leaf.reshape(dims), bs=bs, bc=bc, use_kernel=use_kernel,
-                      interpret=interpret, checksum=checksum)
+                      checksum=checksum)
         cm = dataclasses.replace(cm, shape=tuple(leaf.shape))
         if meter is not None:
             meter.record(name, cm)
@@ -243,9 +236,8 @@ def compress_tree(tree: Any, *, bs: int = 8, bc: int = 128,
     return jax.tree_util.tree_map_with_path(one, tree)
 
 
-def decompress_tree(tree: Any, *, use_kernel: bool = True,
-                    interpret: bool = True) -> Any:
+def decompress_tree(tree: Any, *, use_kernel: bool = True) -> Any:
     return jax.tree_util.tree_map(
-        lambda l: decompress(l, use_kernel=use_kernel, interpret=interpret)
+        lambda l: decompress(l, use_kernel=use_kernel)
         if isinstance(l, CompressedMap) else l,
         tree, is_leaf=lambda l: isinstance(l, CompressedMap))

@@ -64,6 +64,16 @@ implicit rewrites. The current reasons:
                      map bigger than ``vmem_budget_bytes``. The built-in
                      compressed backends self-tile (declare False); the
                      reason exists for registered backends that cannot.
+``narrow-blocks``    on a TPU, blocks narrower than a (8, 128) vreg tile
+                     (the paper's 4x4 NCHW blocks; ``kernels.platform.
+                     tpu_tileable``). No Pallas form addresses them, and
+                     the XLA forms of the stream (an ``(n, 4, 4)`` payload
+                     gather and its expansion) pad each block 64x in HBM
+                     and compile for minutes per site shape. The site
+                     runs the reference masked map and moves no stream
+                     (``measured_bytes`` 0). Off a TPU such blocks run the
+                     selected backend: interpreted kernels, XLA pack and
+                     expand forms.
 
 Layouts. ``tokens`` maps ``(..., S, D)`` tile into ``(block_seq,
 block_ch)`` VMEM blocks. ``nchw`` maps ``(B, C, H, W)`` use the paper's
@@ -72,7 +82,8 @@ kernels' 2-D ``(M, K)`` tile grid as ``(B*C*H, W)`` with ``bs = bc = b``
 — every ``(b, b)`` tile of that matrix is exactly one spatial block of
 one channel (H, W divide by b, so tiles never straddle planes). NCHW
 blocks shrink to the largest divisor of (H, W) (paper: "block size 2
-when the map goes to 2x2") and stay on the selected backend.
+when the map goes to 2x2") and stay on the selected backend, except on
+a TPU (``narrow-blocks``).
 
 New backends register through :func:`register_engine_backend` — model
 code needs no changes, which is the structural point of the registry.
@@ -412,7 +423,7 @@ def _kernel_statics(variant: str, x2: jax.Array, bs: int, bc: int,
     return KernelStatics(variant=variant, t_obj=cfg.t_obj, bs=bs, bc=bc,
                          tm=tm, tk=tk, gtm=gtm, gtk=gtk, pw=pw,
                          grad_mode=cfg.grad_mode,
-                         soft_temp=cfg.soft_temp, interpret=cfg.interpret)
+                         soft_temp=cfg.soft_temp)
 
 
 def _run_pallas(x2: jax.Array, bs: int, bc: int, cfg: ZebraConfig):
@@ -433,7 +444,7 @@ def _mask_pack(x2: jax.Array, bs: int, bc: int, cfg: ZebraConfig):
                             jnp.dtype(x2.dtype).itemsize,
                             int(cfg.vmem_budget_bytes))
     return zebra_mask_pack(x2, t_obj=cfg.t_obj, bs=bs, bc=bc, tm=tm, tk=tk,
-                           window=window, interpret=cfg.interpret)
+                           window=window)
 
 
 def _run_stream(x2: jax.Array, bs: int, bc: int, cfg: ZebraConfig):
@@ -463,8 +474,7 @@ def _run_fused(x2: jax.Array, w: jax.Array, bs: int, bc: int,
     plan = cfg.gemm_plan_for(M, K, bs, bc, x2.dtype, n=w.shape[-1])
     out = zebra_spmm_cs(payload, w, bitmap, bs=bs, bc=bc, bn=plan.bn,
                         stm=plan.stm, stk=plan.stk, caps=plan.caps,
-                        zero_frac_hint=cfg.zero_frac_hint,
-                        interpret=cfg.interpret)
+                        zero_frac_hint=cfg.zero_frac_hint)
     measured = stream_bytes(n_live, bs, bc, x2.dtype, bitmap.size)
     return out.astype(x2.dtype), bitmap, measured
 
@@ -547,8 +557,7 @@ def _validated_stream_impl(x2: jax.Array, bs: int, bc: int, cfg: ZebraConfig,
         def consume():
             out = zebra_spmm_cs(payload, w, bitmap, bs=bs, bc=bc, bn=plan.bn,
                                 stm=plan.stm, stk=plan.stk, caps=plan.caps,
-                                zero_frac_hint=cfg.zero_frac_hint,
-                                interpret=cfg.interpret)
+                                zero_frac_hint=cfg.zero_frac_hint)
             return out.astype(x2.dtype), bitmap.astype(jnp.int8)
 
         def recover():
@@ -597,8 +606,8 @@ def register_engine_backend(spec: BackendSpec, infer_impl: Callable,
 # ---------------------------------------------------------------------------
 
 def _resolve_backend(spec: BackendSpec, *, mode: str, tnet,
-                     degenerate: bool, over_budget: bool = False
-                     ) -> tuple[str, str | None]:
+                     degenerate: bool, over_budget: bool = False,
+                     narrow: bool = False) -> tuple[str, str | None]:
     """Map one site's situation onto a backend the spec can serve.
 
     Returns ``(final backend name, degrade reason | None)`` — the single
@@ -606,7 +615,8 @@ def _resolve_backend(spec: BackendSpec, *, mode: str, tnet,
     call sites). ``over_budget`` only matters for backends declaring
     ``vmem_bounded``: their whole-map working set must fit
     ``vmem_budget_bytes`` (the built-in compressed backends self-tile
-    and declare False, so they never degrade here)."""
+    and declare False, so they never degrade here). ``narrow`` is set
+    on a TPU for blocks no Pallas form can tile."""
     if spec.name == "reference":
         return "reference", None
     if mode == "train" and not spec.trainable:
@@ -618,15 +628,24 @@ def _resolve_backend(spec: BackendSpec, *, mode: str, tnet,
         return "reference", "degenerate-rows"
     if spec.vmem_bounded and over_budget:
         return "reference", "vmem-bounded"
+    if narrow:
+        return "reference", "narrow-blocks"
     return spec.name, None
 
 
-def _log_degrade(site: str, requested: str, reason: str) -> None:
-    key = (site, requested, reason)
-    if key not in _DEGRADE_LOGGED:
+def _log_resolution(site: str, requested: str, label: str,
+                    degraded: bool) -> None:
+    """Every trace of a site logs its resolved label at DEBUG (fields
+    ``zebra_site`` / ``zebra_backend`` on the record, for a handler that
+    must show which backend a traced program ran); a degrade is also
+    logged once per (site, backend, label) at INFO."""
+    _log.debug("zebra_site %r: backend %r resolved as %s", site, requested,
+               label, extra={"zebra_site": site, "zebra_backend": label})
+    key = (site, requested, label)
+    if degraded and key not in _DEGRADE_LOGGED:
         _DEGRADE_LOGGED.add(key)
-        _log.info("zebra_site %r: backend %r degraded to reference (%s)",
-                  site, requested, reason)
+        _log.info("zebra_site %r: backend %r resolved as %s",
+                  site, requested, label)
 
 
 def wants_fused(cfg: ZebraConfig, site: str = "") -> bool:
@@ -707,12 +726,13 @@ def zebra_site(x: jax.Array, cfg: ZebraConfig, *, site: str = "",
     over_budget = (spec.vmem_bounded and
                    dims[0] * dims[1] * jnp.dtype(x.dtype).itemsize
                    > cfg.vmem_budget_bytes)
-    backend, reason = _resolve_backend(spec, mode=cfg.mode, tnet=tnet,
-                                       degenerate=degenerate,
-                                       over_budget=over_budget)
-    if reason is not None:
-        _log_degrade(site, spec.name, reason)
+    from ..kernels.platform import pallas_interpret, tpu_tileable
+    backend, reason = _resolve_backend(
+        spec, mode=cfg.mode, tnet=tnet, degenerate=degenerate,
+        over_budget=over_budget,
+        narrow=not (pallas_interpret() or tpu_tileable(bs, bc)))
     label = backend if reason is None else f"{backend}({reason})"
+    _log_resolution(site, spec.name, label, reason is not None)
 
     # ---- reference: the jnp path (threshold nets live here) ---------------
     if backend == "reference":
@@ -740,10 +760,8 @@ def zebra_site(x: jax.Array, cfg: ZebraConfig, *, site: str = "",
         # bodies (remat) that downstream arithmetic cannot consume. Live
         # blocks keep their values bitwise, so blockmax(|y|) >= t_obj IS
         # the kernel's keep bitmap (dead blocks are exact zeros).
-        yd = jax.lax.stop_gradient(y2)
-        ydb = yd.reshape(dims[0] // bs, bs, dims[1] // bc, bc)
-        keep = (jnp.max(jnp.abs(ydb), axis=(1, 3))
-                >= jnp.asarray(cfg.t_obj, yd.dtype))
+        from ..kernels.zebra_mask import block_keep
+        keep = block_keep(jax.lax.stop_gradient(y2), cfg.t_obj, bs, bc) != 0
         measured = (stream_bytes(jnp.sum(keep.astype(jnp.int32)), bs, bc,
                                  x2.dtype, keep.size)
                     if spec.emits_stream else jnp.int32(0))

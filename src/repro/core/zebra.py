@@ -58,7 +58,6 @@ class ZebraConfig:
     # --- site-engine execution (core.engine) ---
     backend: str = "reference"   # reference | pallas | stream | fused
     site_backends: tuple[tuple[str, str], ...] = ()  # per-site overrides
-    interpret: bool = True       # Pallas interpret mode (CPU containers)
     vmem_budget_bytes: int = 8 * 1024 * 1024
                                  # per-launch VMEM working-set cap the tile
                                  # chooser (tiles_for) sizes comparator

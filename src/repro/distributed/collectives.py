@@ -65,26 +65,14 @@ RING_SITE = "ring"   # ft.breaker site label for the collectives hop boundary
 
 
 # ---------------------------------------------------------------------------
-# shard_map / axis-size compat
+# shard_map / axis size
 # ---------------------------------------------------------------------------
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the public alias (with the
-    ``check_vma`` rename) landed after the 0.4.x line; fall back to
-    ``jax.experimental.shard_map.shard_map(check_rep=False)``. Replica
-    checking stays off either way — the collectives here use
-    ``lax.axis_index``, which is per-shard by construction."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replica checking off — the collectives here
+    use ``lax.axis_index``, which is per-shard by construction."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def axis_size(axis) -> int:

@@ -85,12 +85,14 @@ POLICIES: dict[type, str] = {
 SHED_POLICIES = ("shed",)
 
 # Exception text markers that identify a known transient-infrastructure
-# failure when the raiser didn't use the taxonomy (e.g. jaxlib's
-# XlaRuntimeError). Deliberately narrow: an unrecognized error is a bug
-# and must surface, not retry.
-_TRANSIENT_MARKERS = ("RESOURCE_EXHAUSTED", "UNAVAILABLE", "DEADLINE_EXCEEDED",
-                     "ABORTED", "INTERNAL", "preempt", "socket closed",
-                     "connection reset")
+# failure when the raiser didn't use the taxonomy (e.g. JAX's
+# JaxRuntimeError). Deliberately narrow: an unrecognized error is a bug
+# and must surface, not retry. RESOURCE_EXHAUSTED (HBM/VMEM/SMEM
+# overflow) and INTERNAL (e.g. "Mosaic failed to compile") are left out:
+# both are deterministic for a given program and shape, so a retry would
+# only fail again.
+_TRANSIENT_MARKERS = ("UNAVAILABLE", "DEADLINE_EXCEEDED", "ABORTED",
+                      "preempt", "socket closed", "connection reset")
 _POISON_MARKERS = ("nan", "non-finite", "not finite", "inf loss")
 
 
@@ -110,7 +112,8 @@ def classify(exc: BaseException) -> type[FaultError] | None:
         return None                      # KeyboardInterrupt / SystemExit
     msg = f"{type(exc).__name__}: {exc}"
     low = msg.lower()
-    if type(exc).__name__ == "XlaRuntimeError" or "jaxlib" in type(exc).__module__:
+    if type(exc).__name__ == "JaxRuntimeError":     # jax.errors, by name:
+                                                    # this module imports no jax
         if any(m.lower() in low for m in _TRANSIENT_MARKERS):
             return TransientStep
     if isinstance(exc, FloatingPointError) or \

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 
 from .mask_pack import zebra_mask_pack
 from .pack import zebra_unpack
-from .zebra_mask import zebra_mask
+from .zebra_mask import block_max, expand_blocks, zebra_mask
 
 
 class KernelStatics(NamedTuple):
@@ -70,26 +70,20 @@ class KernelStatics(NamedTuple):
     pw: int                     # pack-pass slot window (budget-capped)
     grad_mode: str
     soft_temp: float
-    interpret: bool
-
-
-def _expand2d(blocks: jax.Array, bs: int, bc: int) -> jax.Array:
-    """(Mb, Kb) per-block values -> (M, K) elementwise broadcast."""
-    return jnp.repeat(jnp.repeat(blocks, bs, axis=0), bc, axis=1)
 
 
 def _mask_forward(x2: jax.Array, s: KernelStatics):
     y2, bitmap = zebra_mask(x2, t_obj=s.t_obj, bs=s.bs, bc=s.bc,
-                            tm=s.tm, tk=s.tk, interpret=s.interpret)
+                            tm=s.tm, tk=s.tk)
     return y2, bitmap, jnp.int32(0)
 
 
 def _stream_forward(x2: jax.Array, s: KernelStatics):
     payload, bitmap, n_live = zebra_mask_pack(
         x2, t_obj=s.t_obj, bs=s.bs, bc=s.bc, tm=s.tm, tk=s.tk,
-        window=s.pw, interpret=s.interpret)
+        window=s.pw)
     y2 = zebra_unpack(payload, bitmap, bs=s.bs, bc=s.bc, stm=s.gtm,
-                      stk=s.gtk, interpret=s.interpret)
+                      stk=s.gtk)
     return y2, bitmap, n_live
 
 
@@ -150,15 +144,12 @@ def _bwd(statics, res, cts):
     if statics.grad_mode == "ste":
         return (gy,)
     if statics.grad_mode == "soft":
-        x2 = res
-        M, K = x2.shape
-        xb = x2.reshape(M // statics.bs, statics.bs,
-                        K // statics.bc, statics.bc)
-        blockmax = jnp.max(jnp.abs(xb), axis=(1, 3))
+        blockmax = block_max(res, statics.bs, statics.bc)
         thr = jnp.asarray(statics.t_obj, blockmax.dtype)
         gate = jax.nn.sigmoid((blockmax - thr) / statics.soft_temp)
-        return (gy * _expand2d(gate, statics.bs, statics.bc).astype(gy.dtype),)
-    mask = _expand2d(res, statics.bs, statics.bc).astype(gy.dtype)
+        return (gy * expand_blocks(gate, statics.bs, statics.bc
+                                   ).astype(gy.dtype),)
+    mask = expand_blocks(res, statics.bs, statics.bc).astype(gy.dtype)
     return (gy * mask,)
 
 

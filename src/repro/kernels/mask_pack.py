@@ -24,9 +24,9 @@ a tiny XLA exclusive scan:
 
 Like the consumers, the pack pass has two executable realizations of the
 one contract, selected by ``gather_kernel`` (default: the Pallas form
-when ``interpret=False``): on CPU containers the identical gather runs
-as one XLA blocked take (``xb[src]``) instead, because the Pallas
-interpreter charges ~100 us per dynamically-indexed window fetch and
+wherever ``kernels.platform.tpu_forms`` holds): elsewhere the identical
+gather runs as one XLA blocked take (``xb[src]``) instead, because the
+Pallas interpreter charges ~100 us per dynamically-indexed window fetch and
 duplicates the ``W`` source operands in its grid carry — the XLA take is
 the faster realization of the same dataflow, bit for bit.
 
@@ -62,17 +62,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils import cdiv
+from .platform import pallas_interpret, tpu_forms
 from .schedule import slot_map
 from .supertile import comparator_tiles, pack_window
+from .zebra_mask import threshold, tile_bitmap, tile_blockmax
 
 
-def _bitmap_kernel(x_ref, bm_ref, *, t_obj: float, bs: int, bc: int):
-    x = x_ref[...]
-    TM, TK = x.shape
-    xb = x.reshape(TM // bs, bs, TK // bc, bc)
-    blockmax = jnp.max(jnp.abs(xb), axis=(1, 3))                  # (tm, tk)
-    bm_ref[...] = (blockmax >= jnp.asarray(t_obj, blockmax.dtype)
-                   ).astype(jnp.int8)
+def _bitmap_kernel(x_ref, bm_ref, *, thr: float, bs: int, bc: int):
+    bm_ref[0, 0] = (tile_blockmax(x_ref[...], bs, bc) >= thr).astype(jnp.int32)
 
 
 def _gather_pack_kernel(src_ref, nl_ref, *refs, window: int):
@@ -89,12 +86,11 @@ def _gather_pack_kernel(src_ref, nl_ref, *refs, window: int):
 
 
 @functools.partial(jax.jit, static_argnames=("t_obj", "bs", "bc", "tm", "tk",
-                                             "window", "gather_kernel",
-                                             "interpret"))
+                                             "window", "gather_kernel"))
 def zebra_mask_pack(x: jax.Array, *, t_obj: float, bs: int = 8, bc: int = 128,
                     tm: int | None = None, tk: int | None = None,
                     window: int | None = None,
-                    gather_kernel: bool | None = None, interpret: bool = True
+                    gather_kernel: bool | None = None
                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Two-phase comparator + compaction over an (M, K) map.
 
@@ -124,17 +120,22 @@ def zebra_mask_pack(x: jax.Array, *, t_obj: float, bs: int = 8, bc: int = 128,
     if nb % W:
         raise ValueError(f"pack window {W} must divide n_blocks {nb}")
     if gather_kernel is None:
-        gather_kernel = not interpret
+        gather_kernel = tpu_forms(bs, bc)
 
     # -- phase 1: parallel comparator, bitmap only --------------------------
-    bitmap = pl.pallas_call(
-        functools.partial(_bitmap_kernel, t_obj=t_obj, bs=bs, bc=bc),
-        grid=(cdiv(M, tm), cdiv(K, tk)),
+    GM, GK = cdiv(M, tm), cdiv(K, tk)
+    bm4 = pl.pallas_call(
+        functools.partial(_bitmap_kernel, thr=threshold(t_obj, x.dtype),
+                          bs=bs, bc=bc),
+        grid=(GM, GK),
         in_specs=[pl.BlockSpec((tm, tk), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((tm // bs, tk // bc), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((nm, nk), jnp.int8),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((1, 1, tm // bs, tk // bc),
+                               lambda i, j: (i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((GM, GK, tm // bs, tk // bc),
+                                       jnp.int32),
+        interpret=pallas_interpret(),
     )(x)
+    bitmap = tile_bitmap(bm4, nm, nk)
 
     # -- phase 2a: ONE exclusive scan = counts, offsets and slot map --------
     # the consumer-order slot map (kernels.schedule): column-grouped, so
@@ -149,7 +150,7 @@ def zebra_mask_pack(x: jax.Array, *, t_obj: float, bs: int = 8, bc: int = 128,
 
     # -- phase 2b: parallel gather-pack over payload slot windows -----------
     if not gather_kernel:
-        # interpret form: the identical gather as one XLA two-index take
+        # XLA form: the identical gather as one XLA two-index take
         # straight off the 4-D block view — no transposed block copy of
         # the whole map on the producer hot path
         x4 = x.reshape(nm, bs, nk, bc)
@@ -172,6 +173,6 @@ def zebra_mask_pack(x: jax.Array, *, t_obj: float, bs: int = 8, bc: int = 128,
             out_specs=pl.BlockSpec((W, bs, bc), lambda s, src, nl: (s, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((nb, bs, bc), x.dtype),
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(src, n_live[None], *([x] * W))
     return payload, bitmap, n_live

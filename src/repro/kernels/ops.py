@@ -1,9 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels run with ``interpret=True`` (Pallas
-executes the kernel body in Python for correctness); on TPU pass
-``interpret=False``. ``zebra_ffn_hidden`` is the fused "Zebra site +
-downstream matmul" used by the LM stack when ``use_kernel=True``.
+Whether a kernel is compiled by Mosaic or run by the Pallas interpreter
+follows the platform (``kernels.platform.pallas_interpret``: compiled on
+a TPU, interpreted on any other backend); no wrapper takes an option for
+it. ``zebra_ffn_hidden`` is the fused "Zebra site + downstream matmul".
 """
 from __future__ import annotations
 
@@ -18,12 +18,11 @@ from .zebra_spmm import zebra_spmm
 from . import ref
 
 
-def zebra_mask_op(x: jax.Array, t_obj: float, bs: int = 8, bc: int = 128,
-                  interpret: bool = True):
+def zebra_mask_op(x: jax.Array, t_obj: float, bs: int = 8, bc: int = 128):
     """(..., M, K) tolerant wrapper; flattens leading dims onto M."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
-    y, bm = zebra_mask(x2, t_obj=t_obj, bs=bs, bc=bc, interpret=interpret)
+    y, bm = zebra_mask(x2, t_obj=t_obj, bs=bs, bc=bc)
     return y.reshape(shape), bm
 
 
@@ -32,29 +31,26 @@ def zebra_spmm_op(x: jax.Array, w: jax.Array, bitmap: jax.Array,
                   stk: int | None = None,
                   caps: tuple[int, ...] | None = None,
                   zero_frac_hint: float | None = None,
-                  scheduled: bool | None = None, interpret: bool = True):
+                  scheduled: bool | None = None):
     return zebra_spmm(x, w, bitmap, bs=bs, bc=bc, stm=stm, stk=stk,
                       caps=caps, zero_frac_hint=zero_frac_hint,
-                      scheduled=scheduled, interpret=interpret)
+                      scheduled=scheduled)
 
 
-def zebra_pack_op(x: jax.Array, bitmap: jax.Array, bs: int = 8, bc: int = 128,
-                  interpret: bool = True):
+def zebra_pack_op(x: jax.Array, bitmap: jax.Array, bs: int = 8, bc: int = 128):
     """Compact live blocks of a masked (M, K) map -> (payload, n_live)."""
-    return zebra_pack(x, bitmap, bs=bs, bc=bc, interpret=interpret)
+    return zebra_pack(x, bitmap, bs=bs, bc=bc)
 
 
 def zebra_unpack_op(payload: jax.Array, bitmap: jax.Array, bs: int = 8,
-                    bc: int = 128, interpret: bool = True):
-    return zebra_unpack(payload, bitmap, bs=bs, bc=bc, interpret=interpret)
+                    bc: int = 128):
+    return zebra_unpack(payload, bitmap, bs=bs, bc=bc)
 
 
 def zebra_mask_pack_op(x: jax.Array, t_obj: float, bs: int = 8, bc: int = 128,
-                       tm: int | None = None, tk: int | None = None,
-                       interpret: bool = True):
+                       tm: int | None = None, tk: int | None = None):
     """Two-phase parallel producer: (M, K) -> (payload, bitmap, n_live)."""
-    return zebra_mask_pack(x, t_obj=t_obj, bs=bs, bc=bc, tm=tm, tk=tk,
-                           interpret=interpret)
+    return zebra_mask_pack(x, t_obj=t_obj, bs=bs, bc=bc, tm=tm, tk=tk)
 
 
 def zebra_spmm_cs_op(payload: jax.Array, w: jax.Array, bitmap: jax.Array,
@@ -62,15 +58,15 @@ def zebra_spmm_cs_op(payload: jax.Array, w: jax.Array, bitmap: jax.Array,
                      stk: int | None = None,
                      caps: tuple[int, ...] | None = None,
                      zero_frac_hint: float | None = None,
-                     scheduled: bool | None = None, interpret: bool = True):
+                     scheduled: bool | None = None):
     """Compressed-stream consumer: payload x (K, N) -> (M, N) fp32."""
     return zebra_spmm_cs(payload, w, bitmap, bs=bs, bc=bc, stm=stm, stk=stk,
                          caps=caps, zero_frac_hint=zero_frac_hint,
-                         scheduled=scheduled, interpret=interpret)
+                         scheduled=scheduled)
 
 
 def zebra_ffn_hidden(x: jax.Array, w_out: jax.Array, t_obj: float,
-                     bs: int = 8, bc: int = 128, interpret: bool = True):
+                     bs: int = 8, bc: int = 128):
     """Fused: h' = zebra(h); y = h' @ W_out, skipping dead blocks.
 
     Streaming form: the two-phase mask_pack producer emits the
@@ -78,7 +74,6 @@ def zebra_ffn_hidden(x: jax.Array, w_out: jax.Array, t_obj: float,
     GEMM consumes the payload."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
-    payload, bm, _ = zebra_mask_pack(x2, t_obj=t_obj, bs=bs, bc=bc,
-                                     interpret=interpret)
-    y = zebra_spmm_cs(payload, w_out, bm, bs=bs, bc=bc, interpret=interpret)
+    payload, bm, _ = zebra_mask_pack(x2, t_obj=t_obj, bs=bs, bc=bc)
+    y = zebra_spmm_cs(payload, w_out, bm, bs=bs, bc=bc)
     return y.reshape(*shape[:-1], w_out.shape[-1]), bm

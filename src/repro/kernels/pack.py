@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import pallas_interpret, tpu_forms
 from .schedule import slot_map
 from .supertile import gather_supertiles, validate_supertile
 
@@ -73,8 +74,8 @@ def _prefix(bitmap: jax.Array) -> tuple[jax.Array, jax.Array]:
 def expand_payload(payload: jax.Array, keep: jax.Array, smap: jax.Array,
                    nm: int, nk: int, bs: int, bc: int) -> jax.Array:
     """THE XLA blocked expansion of a compressed stream back to the dense
-    (M, K) map — shared by zebra_unpack's interpret form and
-    zebra_spmm_cs's interpret prologue, so the two cannot diverge.
+    (M, K) map — shared by zebra_unpack's XLA form and
+    zebra_spmm_cs's expand prologue, so the two cannot diverge.
 
     jnp.where, not multiplication: a dead block's revolving-door slot
     aliases a live block, and masking by * would leak NaN/Inf (and
@@ -85,9 +86,9 @@ def expand_payload(payload: jax.Array, keep: jax.Array, smap: jax.Array,
             .reshape(nm * bs, nk * bc))
 
 
-@functools.partial(jax.jit, static_argnames=("bs", "bc", "interpret"))
-def zebra_pack(x: jax.Array, bitmap: jax.Array, *, bs: int = 8, bc: int = 128,
-               interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+@functools.partial(jax.jit, static_argnames=("bs", "bc"))
+def zebra_pack(x: jax.Array, bitmap: jax.Array, *, bs: int = 8, bc: int = 128
+               ) -> tuple[jax.Array, jax.Array]:
     """Compact live blocks of a masked (M, K) map.
 
     Returns (payload (n_blocks, bs, bc) — live blocks first in consumer
@@ -119,7 +120,7 @@ def zebra_pack(x: jax.Array, bitmap: jax.Array, *, bs: int = 8, bc: int = 128,
                 lambda kc, i, dmap, keep: (dmap[i * nk + kc], 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((nb, bs, bc), x.dtype),
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(dmap, keep, x)
 
     # Slots >= n_live hold either stale dead-block writes or uninitialized
@@ -130,11 +131,10 @@ def zebra_pack(x: jax.Array, bitmap: jax.Array, *, bs: int = 8, bc: int = 128,
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "bc", "stm", "stk",
-                                             "payload_windows", "interpret"))
+                                             "payload_windows"))
 def zebra_unpack(payload: jax.Array, bitmap: jax.Array, *, bs: int = 8,
                  bc: int = 128, stm: int | None = None, stk: int | None = None,
-                 payload_windows: bool | None = None,
-                 interpret: bool = True) -> jax.Array:
+                 payload_windows: bool | None = None) -> jax.Array:
     """Inverse of zebra_pack: (n_blocks, bs, bc) payload -> dense (M, K).
 
     Two executable realizations of the one contract (see mask_pack.py):
@@ -142,7 +142,7 @@ def zebra_unpack(payload: jax.Array, bitmap: jax.Array, *, bs: int = 8,
     ``(stm, stk)`` supertiles (``tiles_for(kind="gather")``; the engine
     passes its budgeted tiles, standalone calls use the default-budget
     chooser) and each step writes its own dense window from R*C
-    dynamically slotted payload windows. The interpret default runs the
+    dynamically slotted payload windows. Off a TPU the default runs the
     identical expansion as one XLA blocked gather (the Pallas
     interpreter charges ~100 us per dynamically-indexed window fetch,
     so the gather is the faster realization of the same dataflow on
@@ -152,7 +152,7 @@ def zebra_unpack(payload: jax.Array, bitmap: jax.Array, *, bs: int = 8,
     M, K = nm * bs, nk * bc
     keep, smap = _prefix(bitmap)
     if payload_windows is None:
-        payload_windows = not interpret
+        payload_windows = tpu_forms(bs, bc)
     if not payload_windows:
         return expand_payload(payload, keep, smap, nm, nk, bs, bc)
 
@@ -180,5 +180,5 @@ def zebra_unpack(payload: jax.Array, bitmap: jax.Array, *, bs: int = 8,
                                    lambda i, kc, smap, keep: (i, kc)),
         ),
         out_shape=jax.ShapeDtypeStruct((M, K), payload.dtype),
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(smap, keep, *([payload] * (R * C)))

@@ -28,7 +28,7 @@ address generation is exactly what cost more than the skipped FLOPs
 only on ``n_live`` (payload slots) + the 1-bit/block index, never on
 slot order — pinned by tests/test_mask_pack.py.
 
-Scheduled consume (the interpret/XLA realization of the consumer
+Scheduled consume (the XLA realization of the consumer
 contract): per column the live blocks are compacted to a static
 **capacity** ``cap >= max(counts)`` chosen from the cached autotuning
 chooser's ladder (``kernels.supertile.gemm_plan``), giving a dense

@@ -7,8 +7,8 @@ column's operand is ONE contiguous slot run — no dynamic-window gathers
 on the hot path. The consumer has three executable realizations of the
 one contract:
 
-* **scheduled form** (``scheduled=True``; the default when
-  ``interpret=True``): the static prefetch schedule slices each
+* **scheduled form** (``scheduled=True``; the default off a TPU or for
+  blocks narrower than a vreg tile): the static prefetch schedule slices each
   column's contiguous slot run at a ladder capacity from the cached
   ``supertile.gemm_plan`` chooser and runs the batched panel GEMM +
   selection-matmul assembly of ``kernels.schedule`` — the realization
@@ -17,8 +17,8 @@ one contract:
   both feed the literal same ``_consume_at_cap`` with identical gated
   operands (live block values are untouched by masking, so compacting
   from the payload and from the dense map give the same arrays).
-* **TPU form** (``payload_windows=True``; default when
-  ``interpret=False``): the grid steps over ``(stm, stk)`` supertiles
+* **TPU form** (``payload_windows=True``; default on a TPU,
+  ``kernels.platform.tpu_forms``): the grid steps over ``(stm, stk)`` supertiles
   and every ``(bs, bc)`` block of the supertile is fetched straight
   from its consumer-order payload slot through its own
   scalar-prefetch-indexed BlockSpec — ``R·C`` windows per step. A dead
@@ -42,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils import cdiv
+from .platform import pallas_interpret, tpu_forms
 from .schedule import consumer_schedule, scheduled_consume
 from .supertile import gemm_plan, validate_supertile
 from .zebra_spmm import (gemm_supertile_body, launch_supertile_gemm,
@@ -63,7 +64,7 @@ def _spmm_cs_kernel(smap_ref, keep_ref, seg_ref, *refs, R: int, C: int,
 
 
 def _payload_window_launch(payload, w, keep, smap, *, bs, bc, stm, stk, bn,
-                           nm, nk, interpret):
+                           nm, nk):
     """The payload-direct TPU form: R*C dynamically-slotted payload
     windows per supertile step."""
     K, N = w.shape
@@ -95,30 +96,28 @@ def _payload_window_launch(payload, w, keep, smap, *, bs, bc, stm, stk, bn,
             scratch_shapes=[pltpu.VMEM((stm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((nm * bs, N), jnp.float32),
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(smap, keep, seg, *([payload] * (R * C)), w)
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "bc", "bn", "stm", "stk",
                                              "caps", "zero_frac_hint",
-                                             "scheduled", "payload_windows",
-                                             "interpret"))
+                                             "scheduled", "payload_windows"))
 def zebra_spmm_cs(payload: jax.Array, w: jax.Array, bitmap: jax.Array, *,
                   bs: int = 8, bc: int = 128, bn: int | None = None,
                   stm: int | None = None, stk: int | None = None,
                   caps: tuple[int, ...] | None = None,
                   zero_frac_hint: float | None = None,
                   scheduled: bool | None = None,
-                  payload_windows: bool | None = None,
-                  interpret: bool = True) -> jax.Array:
+                  payload_windows: bool | None = None) -> jax.Array:
     """(n_blocks, bs, bc) payload x (K, N) weight -> (M, N) fp32.
 
     ``bitmap`` is the (M//bs, K//bc) keep map; payload slots follow the
     consumer order of ``kernels.schedule`` (``zebra_mask_pack``'s
     emission order). Plans default from the same cached chooser as
     ``zebra_spmm`` — the two must tile alike for their bitwise parity
-    to hold. ``scheduled=None`` picks the scheduled XLA form iff
-    ``interpret``; ``payload_windows`` selects between the two Pallas
+    to hold. ``scheduled=None`` picks the scheduled XLA form unless
+    ``platform.tpu_forms(bs, bc)``; ``payload_windows`` selects between the two Pallas
     kernel-form realizations when ``scheduled`` is off.
     """
     nm, nk = bitmap.shape
@@ -132,31 +131,32 @@ def zebra_spmm_cs(payload: jax.Array, w: jax.Array, bitmap: jax.Array, *,
                      zero_frac=zero_frac_hint)
     stm, stk, bn = stm or plan.stm, stk or plan.stk, min(bn or plan.bn, N)
     validate_supertile(M, K, bs, bc, stm, stk)
+    kernel_forms = tpu_forms(bs, bc)
     if scheduled is None:
         # explicit payload_windows (either value) asks for a kernel-form
-        # realization; otherwise interpret picks the scheduled XLA form
-        scheduled = interpret and payload_windows is None
+        # realization; otherwise the scheduled XLA form runs wherever the
+        # TPU forms do not
+        scheduled = not kernel_forms and payload_windows is None
     if scheduled:
         sched = consumer_schedule(bitmap)
         return scheduled_consume(payload, w, sched, caps or plan.caps,
                                  from_payload=True, nm=nm, nk=nk,
                                  bs=bs, bc=bc)
     if payload_windows is None:
-        payload_windows = not interpret
+        payload_windows = kernel_forms
     sched = consumer_schedule(bitmap)
     keep = sched.keep.reshape(-1)
     smap = sched.slot.reshape(-1).astype(jnp.int32)      # block -> slot
 
     if payload_windows:
         return _payload_window_launch(payload, w, keep, smap, bs=bs, bc=bc,
-                                      stm=stm, stk=stk, bn=bn, nm=nm, nk=nk,
-                                      interpret=interpret)
+                                      stm=stm, stk=stk, bn=bn, nm=nm, nk=nk)
 
-    # interpret form: one XLA blocked gather (pack.expand_payload, shared
+    # expand form: one XLA blocked gather (pack.expand_payload, shared
     # with zebra_unpack) expands the stream back to the dense operand;
     # the supertiled GEMM kernel (shared with zebra_spmm) re-gates every
     # block by keep, so slot-replayed blocks never leak.
     from .pack import expand_payload
     x2 = expand_payload(payload, keep, smap, nm, nk, bs, bc)
     return launch_supertile_gemm(x2, w, keep, bs=bs, bc=bc, stm=stm, stk=stk,
-                                 bn=bn, interpret=interpret)
+                                 bn=bn)

@@ -31,7 +31,8 @@ dots per step in ascending K order — the same per-row summation order
 for every legal supertile choice, so retiling does not move the result.
 
 Two executable realizations of the one contract, selected by
-``scheduled`` (default: the scheduled XLA form when ``interpret=True``):
+``scheduled`` (default: the scheduled XLA form wherever the TPU form
+does not run — ``kernels.platform.tpu_forms``):
 
 * **scheduled form** (CPU containers / XLA): the static prefetch
   schedule of ``kernels.schedule`` compacts each K column's live blocks
@@ -61,6 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils import cdiv
+from .platform import pallas_interpret, tpu_forms
 from .schedule import consumer_schedule, scheduled_consume
 from .supertile import gemm_plan, validate_supertile
 
@@ -133,8 +135,8 @@ def seg_live_and_kmap(keep: jax.Array, nm: int, nk: int, R: int, C: int
 
 
 def launch_supertile_gemm(x2: jax.Array, w: jax.Array, keep: jax.Array, *,
-                          bs: int, bc: int, stm: int, stk: int, bn: int,
-                          interpret: bool) -> jax.Array:
+                          bs: int, bc: int, stm: int, stk: int, bn: int
+                          ) -> jax.Array:
     """Launch the supertiled GEMM over a dense (M, K) activation operand
     (raw or blocked-expanded — dead blocks are keep-gated in-kernel)."""
     M, K = x2.shape
@@ -162,27 +164,27 @@ def launch_supertile_gemm(x2: jax.Array, w: jax.Array, keep: jax.Array, *,
             scratch_shapes=[pltpu.VMEM((stm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(keep, seg, kmap, x2, w)
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "bc", "bn", "stm", "stk",
                                              "caps", "zero_frac_hint",
-                                             "scheduled", "interpret"))
+                                             "scheduled"))
 def zebra_spmm(x: jax.Array, w: jax.Array, bitmap: jax.Array, *,
                bs: int = 8, bc: int = 128, bn: int | None = None,
                stm: int | None = None, stk: int | None = None,
                caps: tuple[int, ...] | None = None,
                zero_frac_hint: float | None = None,
-               scheduled: bool | None = None,
-               interpret: bool = True) -> jax.Array:
+               scheduled: bool | None = None) -> jax.Array:
     """(M,K) x (K,N) with (M//bs, K//bc) keep-bitmap -> (M,N) fp32.
 
     ``stm``/``stk``/``bn`` size the kernel-form GEMM supertile and
     ``caps`` the scheduled form's capacity ladder — both default from
     the cached ``supertile.gemm_plan`` chooser (``zero_frac_hint``
     tightens the ladder; the engine threads its config hint through).
-    ``scheduled=None`` picks the scheduled XLA form iff ``interpret``."""
+    ``scheduled=None`` picks the scheduled XLA form unless
+    ``platform.tpu_forms(bs, bc)``."""
     M, K = x.shape
     K2, N = w.shape
     assert K2 == K and bitmap.shape == (M // bs, K // bc), (bitmap.shape, M, K)
@@ -191,7 +193,7 @@ def zebra_spmm(x: jax.Array, w: jax.Array, bitmap: jax.Array, *,
     stm, stk, bn = stm or plan.stm, stk or plan.stk, min(bn or plan.bn, N)
     validate_supertile(M, K, bs, bc, stm, stk)
     if scheduled is None:
-        scheduled = interpret
+        scheduled = not tpu_forms(bs, bc)
     if scheduled:
         sched = consumer_schedule(bitmap)
         return scheduled_consume(x, w, sched, caps or plan.caps,
@@ -199,4 +201,4 @@ def zebra_spmm(x: jax.Array, w: jax.Array, bitmap: jax.Array, *,
                                  bs=bs, bc=bc)
     keep = bitmap.reshape(-1).astype(jnp.int32)
     return launch_supertile_gemm(x, w, keep, bs=bs, bc=bc, stm=stm, stk=stk,
-                                 bn=bn, interpret=interpret)
+                                 bn=bn)

@@ -11,13 +11,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: axis_types=Auto exists only on
-    newer releases; older ones default to Auto semantics without it."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis in Auto sharding mode."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -28,11 +28,12 @@ from ..data import LMDatasetConfig, lm_batch
 from ..distributed import sharding as shd
 from ..models.lm import LM
 from ..serve.bucket import pow2_bucket, pow2_ceil
+from .cache import use_compile_cache
 from .mesh import make_host_mesh
 from .steps import _next_token, make_generate, make_prefill
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-4b")
     ap.add_argument("--reduced", action="store_true")
@@ -87,25 +88,43 @@ def main() -> None:
                     help="run the continuous engine loop under the "
                          "crash-recoverable supervisor (per-tick "
                          "snapshots + classified restore/backoff)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    backend = args.backend or ("stream" if args.use_kernel else "")
-    cfg = configs.reduced(args.arch) if args.reduced else configs.get(args.arch)
-    cfg = cfg.replace(param_dtype="bfloat16",
-                      zebra_sites=tuple(cfg.zebra_sites) + ("kv_cache",),
-                      zebra_t_obj=args.t_obj, zebra_backend=backend,
-                      zebra_validation=args.validate)
+
+def serve_config(args: argparse.Namespace, base=None):
+    """The served LMConfig: ``base`` (default: ``--arch``, ``--reduced``)
+    in bf16 with the KV-cache site added and the CLI's Zebra options."""
+    cfg = base or (configs.reduced(args.arch) if args.reduced
+                   else configs.get(args.arch))
+    return cfg.replace(param_dtype="bfloat16",
+                       zebra_sites=tuple(cfg.zebra_sites) + ("kv_cache",),
+                       zebra_t_obj=args.t_obj,
+                       zebra_backend=args.backend or (
+                           "stream" if args.use_kernel else ""),
+                       zebra_validation=args.validate)
+
+
+def build(args: argparse.Namespace, cfg):
+    """Mesh, model and sharded random params (``PRNGKey(0)``) for ``cfg``."""
     mesh = make_host_mesh(model=args.model_parallel)
     model = LM(cfg)
-
     params = jax.jit(model.init)(jax.random.PRNGKey(0))
     pshard = jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s),
         shd.param_specs(params, cfg, mesh), is_leaf=lambda x: isinstance(x, P))
-    params = jax.device_put(params, pshard)
+    return mesh, model, jax.device_put(params, pshard)
+
+
+def main() -> None:
+    args = parse_args()
+    use_compile_cache()
+    cfg = serve_config(args)
+    backend = cfg.zebra_backend
+    mesh, model, params = build(args, cfg)
 
     if args.requests:
-        return serve_continuous(args, cfg, mesh, model, params)
+        serve_continuous(args, cfg, mesh, model, params)
+        return
 
     key = jax.random.PRNGKey(args.seed)
     prefill = jax.jit(make_prefill(model, mesh), static_argnames=())
@@ -317,9 +336,10 @@ def model_prefill_pad(prefill_fn, params, prompts, cache_len, enc=None,
     return logits, (caches, enc_out), aux
 
 
-def serve_continuous(args, cfg, mesh, model, params) -> None:
-    """``--requests N``: run a synthetic heavy-traffic trace through the
-    continuous-batching engine and print its throughput report."""
+def serve_continuous(args, cfg, mesh, model, params, trace=None) -> dict:
+    """``--requests N``: run a synthetic heavy-traffic trace (or the given
+    ``trace``) through the continuous-batching engine, print its
+    throughput report and return it."""
     from ..ft import FTConfig
     from ..serve import ServeEngine, synthetic_trace
 
@@ -329,11 +349,12 @@ def serve_continuous(args, cfg, mesh, model, params) -> None:
                       validation=args.validate,
                       temperature=args.temperature, seed=args.seed,
                       queue_bound=args.queue_bound)
-    trace = synthetic_trace(
-        args.requests, vocab=cfg.vocab, seed=args.seed,
-        prompt_lo=max(args.prompt_len // 4, 4), prompt_hi=args.prompt_len,
-        gen_lo=max(args.gen // 4, 1), gen_hi=args.gen,
-        deadline_ticks=args.deadline_ticks or None)
+    if trace is None:
+        trace = synthetic_trace(
+            args.requests, vocab=cfg.vocab, seed=args.seed,
+            prompt_lo=max(args.prompt_len // 4, 4), prompt_hi=args.prompt_len,
+            gen_lo=max(args.gen // 4, 1), gen_hi=args.gen,
+            deadline_ticks=args.deadline_ticks or None)
     ft_cfg = FTConfig(jitter_seed=args.seed) if args.supervise else None
     rep = eng.run(trace, preempt_after=args.preempt_after, ft_cfg=ft_cfg)
     print(f"[serve] {cfg.name} continuous: {rep['n_requests']} requests "
@@ -353,6 +374,7 @@ def serve_continuous(args, cfg, mesh, model, params) -> None:
           f"/{rep['decode_shape_bound']}  prefill {rep['prefill_shapes']}"
           f"/{rep['prefill_shape_bound']}  reconcile max "
           f"|measured-predicted| {rep['reconcile_max_delta_bytes']:.2f} B")
+    return rep
 
 
 if __name__ == "__main__":

@@ -26,6 +26,7 @@ from ..distributed import sharding as shd
 from ..ft import FTConfig, StepSupervisor
 from ..models.lm import LM
 from ..optim import adamw, warmup_cosine
+from .cache import use_compile_cache
 from .mesh import make_host_mesh
 from .steps import make_train_state_shape, make_train_step, train_state_specs
 
@@ -45,9 +46,7 @@ def main() -> None:
     ap.add_argument("--t-obj", type=float, default=0.1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
-
-    if jax.process_count() > 1:  # multi-host slice: controller handles init
-        pass
+    use_compile_cache()
 
     cfg = configs.reduced(args.arch) if args.reduced else configs.get(args.arch)
     cfg = cfg.replace(zebra_t_obj=args.t_obj)

@@ -17,13 +17,15 @@ import numpy as np
 def he_normal(key, shape, dtype=jnp.float32, fan_in=None):
     if fan_in is None:
         fan_in = int(np.prod(shape[1:])) if len(shape) > 1 else shape[0]
-    return jax.random.normal(key, shape, dtype) * np.sqrt(2.0 / fan_in)
+    return jax.random.normal(key, shape, dtype) * float(np.sqrt(2.0 / fan_in))
 
 
 def lecun_normal(key, shape, dtype=jnp.float32, fan_in=None):
     if fan_in is None:
         fan_in = int(np.prod(shape[1:])) if len(shape) > 1 else shape[0]
-    return jax.random.normal(key, shape, dtype) * np.sqrt(1.0 / fan_in)
+    # a Python float scale keeps ``dtype`` (a numpy scalar would promote
+    # bf16 weights to f32 and double their HBM footprint)
+    return jax.random.normal(key, shape, dtype) * float(np.sqrt(1.0 / fan_in))
 
 
 # ----------------------------------------------------------------------------

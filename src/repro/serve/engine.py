@@ -266,8 +266,9 @@ class ServeEngine:
         # pad to the ladder floor BEFORE paging out: prefill buckets below
         # page_tokens would otherwise fall to the dense leaf path — padded,
         # admission traffic rides the stream like eviction traffic (the
-        # zero tail is all dead blocks, nearly free on the wire)
-        self.pool.page_out(r.rid, self._pad_like(caches, self.c_lo))
+        # zero tail is all dead blocks, nearly free on the wire). Longer
+        # prefills keep their own (power-of-two) length.
+        self.pool.page_out(r.rid, self._pad_like(caches, max(self.c_lo, pb)))
         return self.pool.page_in(r.rid)
 
     def _evict(self, lane: int, tick: int) -> None:

@@ -59,14 +59,13 @@ class PagedKVPool:
 
     def __init__(self, *, page_tokens: int = 16, bs: int = 8, bc: int = 128,
                  validation: str = "off", use_kernel: bool = False,
-                 interpret: bool = True, breaker=None):
+                 breaker=None):
         if page_tokens & (page_tokens - 1) or page_tokens < 1:
             raise ValueError(f"page_tokens must be a power of two, got {page_tokens}")
         self.page_tokens = page_tokens
         self.bs, self.bc = bs, bc
         self.validation = validate_level(validation)
         self.use_kernel = use_kernel
-        self.interpret = interpret
         self.breaker = breaker    # ft.breaker.BreakerBoard | None — the
                                   # page-ingest circuit: open means pages
                                   # skip compress+validate wholesale
@@ -98,7 +97,6 @@ class PagedKVPool:
             bs, bc = self._eff_blocks(*page2d.shape)
             fn = jax.jit(functools.partial(
                 compress, bs=bs, bc=bc, use_kernel=self.use_kernel,
-                interpret=self.interpret,
                 checksum=(self.validation == "checksum")))
             self._enc[key] = fn
         return fn(page2d)
@@ -108,8 +106,7 @@ class PagedKVPool:
         fn = self._dec.get(key)
         if fn is None:
             fn = jax.jit(functools.partial(
-                decompress, use_kernel=self.use_kernel,
-                interpret=self.interpret))
+                decompress, use_kernel=self.use_kernel))
             self._dec[key] = fn
         return fn(cm)
 

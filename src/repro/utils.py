@@ -7,6 +7,7 @@ from typing import Any, Iterable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend import core as jex_core
 
 PyTree = Any
 
@@ -81,9 +82,9 @@ def pallas_eqns(jaxpr) -> list:
             continue                     # kernel bodies never nest launches
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (tuple, list)) else (v,)):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jex_core.ClosedJaxpr):
                     out.extend(pallas_eqns(sub.jaxpr))
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jex_core.Jaxpr):
                     out.extend(pallas_eqns(sub))
     return out
 

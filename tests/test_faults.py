@@ -103,12 +103,30 @@ def test_classify_and_policies():
     assert policy_for(AssertionError()) is None
 
 
+def test_deterministic_device_errors_are_not_transient():
+    """A kernel the compiler refuses, or a program that overflows HBM or
+    SMEM, fails the same way on every retry: both must surface as bugs
+    (``None``), never be retried with backoff."""
+    mosaic = jax.errors.JaxRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel: Invalid relayout")
+    hbm = jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+        "allocate 2.00G. That was not possible. There are 1.50G free.")
+    smem = RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in memory "
+                        "space smem. Used 2.00M of 1.00M smem.")
+    for exc in (mosaic, hbm, smem):
+        assert classify(exc) is None, exc
+        assert policy_for(exc) is None
+    # a genuinely transient runtime status still retries
+    assert classify(jax.errors.JaxRuntimeError(
+        "UNAVAILABLE: TPU worker lost")) is TransientStep
+
+
 # ---------------------------------------------------------------------------
 # Engine boundary (in-graph check + lax.cond recompute-from-dense)
 # ---------------------------------------------------------------------------
 
-_ENG = ZebraConfig(t_obj=0.8, block_seq=8, block_ch=128, mode="infer",
-                   interpret=True)
+_ENG = ZebraConfig(t_obj=0.8, block_seq=8, block_ch=128, mode="infer")
 
 
 def _eng_x():

@@ -116,8 +116,9 @@ def test_sharding_rules_on_8_devices():
     assert out["unknown"] == []
 
     # batch_spec: full DP when divisible, all dropped at batch=1;
-    # pure-DP adds "model" to the batch axes
-    assert out["bs_8"] == [["data"], None, None]
+    # pure-DP adds "model" to the batch axes (PartitionSpec normalises a
+    # one-axis tuple to the bare axis name)
+    assert out["bs_8"] == ["data", None, None]
     assert out["bs_1"] == [None, None, None]
     assert out["bs_dp"] == [["data", "model"], None, None]
 
