@@ -31,7 +31,6 @@ import argparse
 import contextlib
 import dataclasses
 import json
-import logging
 import os
 import sys
 import time
@@ -76,33 +75,24 @@ def _labels(rec: dict) -> dict:
     return {site: sorted(v) for site, v in sorted(rec.items())}
 
 
-class _Resolutions(logging.Handler):
-    """Collects ``{site: {resolved backend label}}`` from the site
-    engine's per-trace DEBUG records."""
-
-    def __init__(self):
-        super().__init__(logging.DEBUG)
-        self.seen: dict[str, set[str]] = {}
-
-    def emit(self, record: logging.LogRecord) -> None:
-        site = getattr(record, "zebra_site", None)
-        if site is not None:
-            self.seen.setdefault(site, set()).add(record.zebra_backend)
-
-
 @contextlib.contextmanager
 def resolutions():
-    """The backend every site traced inside the block resolved to. A
-    function traced before the block records nothing."""
-    log = logging.getLogger("repro.engine")
-    handler, level = _Resolutions(), log.level
-    log.addHandler(handler)
-    log.setLevel(logging.DEBUG)
+    """The backend every site traced inside the block resolved to, as the
+    site engine resolves it. A function traced before the block records
+    nothing."""
+    from repro.core import engine
+    seen: dict[str, set[str]] = {}
+    resolved = engine._log_resolution
+
+    def record(site, requested, label, degraded):
+        seen.setdefault(site, set()).add(label)
+        resolved(site, requested, label, degraded)
+
+    engine._log_resolution = record
     try:
-        yield handler.seen
+        yield seen
     finally:
-        log.removeHandler(handler)
-        log.setLevel(level)
+        engine._log_resolution = resolved
 
 
 def _check_cnn_sites(labels) -> None:

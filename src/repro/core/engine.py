@@ -635,12 +635,7 @@ def _resolve_backend(spec: BackendSpec, *, mode: str, tnet,
 
 def _log_resolution(site: str, requested: str, label: str,
                     degraded: bool) -> None:
-    """Every trace of a site logs its resolved label at DEBUG (fields
-    ``zebra_site`` / ``zebra_backend`` on the record, for a handler that
-    must show which backend a traced program ran); a degrade is also
-    logged once per (site, backend, label) at INFO."""
-    _log.debug("zebra_site %r: backend %r resolved as %s", site, requested,
-               label, extra={"zebra_site": site, "zebra_backend": label})
+    """A degrade is logged once per (site, backend, label) at INFO."""
     key = (site, requested, label)
     if degraded and key not in _DEGRADE_LOGGED:
         _DEGRADE_LOGGED.add(key)
@@ -687,10 +682,22 @@ def zebra_site(x: jax.Array, cfg: ZebraConfig, *, site: str = "",
     reference path. Capability misses degrade to reference with the
     reason in ``SiteAux.backend`` (see module docstring).
 
+    Everything the site runs, on every backend and layout, sits under the
+    named scope ``zebra.<site>`` (``zebra`` for an unnamed site), so the
+    compiled program's ops, fused or not, carry the site in their
+    ``op_name`` and a device trace can attribute them.
+
     Returns ``(y, SiteAux)``. Without ``w``, y is the masked map (bitwise
     identical across reference/pallas/stream). With ``w`` (fused), y is
     the downstream product with dead blocks skipped.
     """
+    with jax.named_scope(f"zebra.{site}" if site else "zebra"):
+        return _site(x, cfg, site=site, layout=layout, tnet=tnet, w=w)
+
+
+def _site(x: jax.Array, cfg: ZebraConfig, *, site: str, layout: str,
+          tnet: dict | None, w: jax.Array | None
+          ) -> tuple[jax.Array, SiteAux]:
     spec = backend_spec(cfg.backend_for(site))
     if w is not None and not spec.consumes_w:
         raise ValueError(
@@ -712,8 +719,8 @@ def zebra_site(x: jax.Array, cfg: ZebraConfig, *, site: str = "",
         degenerate = False
     elif layout == "tokens":
         if x.ndim == 2:                 # bare (M, K) map: one-sample batch
-            y, aux = zebra_site(x[None], cfg, site=site, layout=layout,
-                                tnet=tnet, w=w)
+            y, aux = _site(x[None], cfg, site=site, layout=layout,
+                           tnet=tnet, w=w)
             return y[0], aux
         bs, bc, degenerate = _tokens_blocks(x, cfg)
         cfg = cfg.replace(block_seq=bs, block_ch=bc)

@@ -134,6 +134,7 @@ def zebra_mask_pack(x: jax.Array, *, t_obj: float, bs: int = 8, bc: int = 128,
         out_shape=jax.ShapeDtypeStruct((GM, GK, tm // bs, tk // bc),
                                        jnp.int32),
         interpret=pallas_interpret(),
+        name="zebra_mask_pack",
     )(x)
     bitmap = tile_bitmap(bm4, nm, nk)
 
@@ -174,5 +175,6 @@ def zebra_mask_pack(x: jax.Array, *, t_obj: float, bs: int = 8, bc: int = 128,
         ),
         out_shape=jax.ShapeDtypeStruct((nb, bs, bc), x.dtype),
         interpret=pallas_interpret(),
+        name="zebra_mask_pack",
     )(src, n_live[None], *([x] * W))
     return payload, bitmap, n_live

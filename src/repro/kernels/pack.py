@@ -121,6 +121,7 @@ def zebra_pack(x: jax.Array, bitmap: jax.Array, *, bs: int = 8, bc: int = 128
         ),
         out_shape=jax.ShapeDtypeStruct((nb, bs, bc), x.dtype),
         interpret=pallas_interpret(),
+        name="zebra_pack",
     )(dmap, keep, x)
 
     # Slots >= n_live hold either stale dead-block writes or uninitialized
@@ -181,4 +182,5 @@ def zebra_unpack(payload: jax.Array, bitmap: jax.Array, *, bs: int = 8,
         ),
         out_shape=jax.ShapeDtypeStruct((M, K), payload.dtype),
         interpret=pallas_interpret(),
+        name="zebra_unpack",
     )(smap, keep, *([payload] * (R * C)))

@@ -97,6 +97,7 @@ def _payload_window_launch(payload, w, keep, smap, *, bs, bc, stm, stk, bn,
         ),
         out_shape=jax.ShapeDtypeStruct((nm * bs, N), jnp.float32),
         interpret=pallas_interpret(),
+        name="zebra_spmm_cs",
     )(smap, keep, seg, *([payload] * (R * C)), w)
 
 
@@ -159,4 +160,4 @@ def zebra_spmm_cs(payload: jax.Array, w: jax.Array, bitmap: jax.Array, *,
     from .pack import expand_payload
     x2 = expand_payload(payload, keep, smap, nm, nk, bs, bc)
     return launch_supertile_gemm(x2, w, keep, bs=bs, bc=bc, stm=stm, stk=stk,
-                                 bn=bn)
+                                 bn=bn, name="zebra_spmm_cs")

@@ -120,5 +120,6 @@ def zebra_mask(x: jax.Array, *, t_obj: float, bs: int = 8, bc: int = 128,
             jax.ShapeDtypeStruct((GM, GK, tm // bs, tk // bc), jnp.int32),
         ],
         interpret=pallas_interpret(),
+        name="zebra_mask",
     )(x)
     return y, tile_bitmap(bm4, nm, nk)

@@ -135,10 +135,11 @@ def seg_live_and_kmap(keep: jax.Array, nm: int, nk: int, R: int, C: int
 
 
 def launch_supertile_gemm(x2: jax.Array, w: jax.Array, keep: jax.Array, *,
-                          bs: int, bc: int, stm: int, stk: int, bn: int
-                          ) -> jax.Array:
+                          bs: int, bc: int, stm: int, stk: int, bn: int,
+                          name: str = "zebra_spmm") -> jax.Array:
     """Launch the supertiled GEMM over a dense (M, K) activation operand
-    (raw or blocked-expanded — dead blocks are keep-gated in-kernel)."""
+    (raw or blocked-expanded — dead blocks are keep-gated in-kernel).
+    ``name`` is the kernel's name in the compiled program and its trace."""
     M, K = x2.shape
     N = w.shape[1]
     nm, nk = M // bs, K // bc
@@ -165,6 +166,7 @@ def launch_supertile_gemm(x2: jax.Array, w: jax.Array, keep: jax.Array, *,
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         interpret=pallas_interpret(),
+        name=name,
     )(keep, seg, kmap, x2, w)
 
 

@@ -3,6 +3,9 @@
 ResNet-18: stem conv3x3 -> 4 stages of 2 BasicBlocks (64,128,256,512).
 ResNet-56: CIFAR style, 3 stages of 9 BasicBlocks (16,32,64).
 Zebra is applied after every ReLU (both intra-block and post-residual).
+``apply`` runs each layer group under a named scope (``stem``,
+``s<stage>b<block>``, ``head``), so every op of the compiled forward
+names its layer.
 """
 from __future__ import annotations
 
@@ -91,15 +94,20 @@ class ResNet:
         p, s, z = variables["params"], variables["state"], variables.get("zebra")
         sites = ZebraSites(zcfg)
         new_state = {}
-        x = conv_apply(p["stem"], x)
-        x, new_state["bn_stem"] = bn_apply(p["bn_stem"], s["bn_stem"], x, train)
-        x = relu(x)
-        x = sites(x, z)
+        with jax.named_scope("stem"):
+            x = conv_apply(p["stem"], x)
+            x, new_state["bn_stem"] = bn_apply(p["bn_stem"], s["bn_stem"], x,
+                                               train)
+            x = relu(x)
+            x = sites(x, z)
         for si, bi, c_in, c_out, stride in self._walk():
             nm = f"s{si}b{bi}"
-            x, new_state[nm] = _block_apply(p[nm], s[nm], x, stride, train, sites, z)
-        x = global_avg_pool(x)
-        logits = dense_apply(p["fc"], x)
+            with jax.named_scope(nm):
+                x, new_state[nm] = _block_apply(p[nm], s[nm], x, stride,
+                                                train, sites, z)
+        with jax.named_scope("head"):
+            x = global_avg_pool(x)
+            logits = dense_apply(p["fc"], x)
         return logits, new_state, sites.auxes
 
     def map_specs(self, in_hw: int | None = None, zcfg: ZebraConfig = ZebraConfig()):

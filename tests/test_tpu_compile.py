@@ -13,7 +13,9 @@ xdist worker owns the TPU compiler.
 """
 from __future__ import annotations
 
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -116,3 +118,89 @@ def test_nchw_site_compiles_to_the_reference_path(one_chip, as_tpu):
     hlo = _compile(site, x)
     assert labels == ["reference(narrow-blocks)"]
     assert "tpu_custom_call" not in hlo
+
+
+_METADATA = re.compile(r", metadata=\{[^}]*\}")
+_LOCATIONS = re.compile(
+    r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*", re.M)
+
+
+def test_scopes_leave_the_chip_program_unchanged(one_chip, as_tpu,
+                                                 monkeypatch):
+    """The ResNet-18 Tiny-ImageNet forward at batch 128 with bfloat16 maps,
+    compiled for a v5e, is the same program with and without its named
+    scopes once metadata is stripped: the scopes name ops, and change no
+    instruction, fusion or layout."""
+    from repro.core import ZebraConfig
+    from repro.models.cnn import build
+    zc = ZebraConfig(t_obj=1.2, block_hw=4, backend="stream",
+                     use_tnet=False, mode="infer")
+    m = build("resnet18", num_classes=200, in_hw=64)
+
+    def forward_hlo():
+        v = jax.eval_shape(lambda k: m.init(k, zc), jax.random.PRNGKey(0))
+        v = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), v)
+        x = jax.ShapeDtypeStruct((128, 3, 64, 64), jnp.bfloat16,
+                                 sharding=one_chip)
+        return _compile(lambda v, x: m.apply(v, x, False, zc)[0], v, x)
+
+    with_scopes = forward_hlo()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    without = forward_hlo()
+    assert "/s0b0/zebra.z1/" in with_scopes and "/zebra.z" not in without
+    code = [_METADATA.sub("", _LOCATIONS.sub("", h))
+            for h in (with_scopes, without)]
+    assert "ENTRY" in code[0] and "FileNames" not in code[0]
+    assert code[0] == code[1]
+
+
+def _kernel_cases():
+    """Each Zebra kernel launched outside the jitted wrapper that usually
+    encloses it, so only the kernel's own ``name=`` can name it: (call,
+    argument shapes as (shape, dtype), the names of its custom calls)."""
+    from repro.kernels.mask_pack import zebra_mask_pack
+    from repro.kernels.pack import zebra_pack, zebra_unpack
+    from repro.kernels.spmm_cs import zebra_spmm_cs
+    from repro.kernels.zebra_mask import zebra_mask
+    from repro.kernels.zebra_spmm import zebra_spmm
+    m, k, n = 512, 1024, 256
+    x, w = ((m, k), jnp.bfloat16), ((k, n), jnp.bfloat16)
+    bitmap = ((m // BS, k // BC), jnp.int8)
+    payload = (((m // BS) * (k // BC), BS, BC), jnp.bfloat16)
+    return {
+        "zebra_mask": (lambda x: zebra_mask.__wrapped__(
+            x, t_obj=1.6, bs=BS, bc=BC), (x,), ["zebra_mask"]),
+        "zebra_pack": (lambda x, b: zebra_pack.__wrapped__(
+            x, b, bs=BS, bc=BC), (x, bitmap), ["zebra_pack"]),
+        "zebra_unpack": (lambda p, b: zebra_unpack.__wrapped__(
+            p, b, bs=BS, bc=BC), (payload, bitmap), ["zebra_unpack"]),
+        "zebra_mask_pack": (lambda x: zebra_mask_pack.__wrapped__(
+            x, t_obj=1.6, bs=BS, bc=BC), (x,), ["zebra_mask_pack"] * 2),
+        "zebra_spmm": (lambda x, w, b: zebra_spmm.__wrapped__(
+            x, w, b, bs=BS, bc=BC), (x, w, bitmap), ["zebra_spmm"]),
+        "zebra_spmm_cs": (lambda p, w, b: zebra_spmm_cs.__wrapped__(
+            p, w, b, bs=BS, bc=BC), (payload, w, bitmap), ["zebra_spmm_cs"]),
+        "zebra_spmm_cs-expand": (lambda p, w, b: zebra_spmm_cs.__wrapped__(
+            p, w, b, bs=BS, bc=BC, payload_windows=False),
+            (payload, w, bitmap), ["zebra_spmm_cs"]),
+    }
+
+
+_CUSTOM_CALL = re.compile(r"^\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = [^\n]*"
+                          r"custom_call_target=\"tpu_custom_call\"", re.M)
+
+
+@pytest.mark.parametrize("case", ["zebra_mask", "zebra_pack", "zebra_unpack",
+                                  "zebra_mask_pack", "zebra_spmm",
+                                  "zebra_spmm_cs", "zebra_spmm_cs-expand"])
+def test_kernel_is_named_in_the_compiled_program(one_chip, as_tpu, case):
+    """The compiled program (and so a device trace) names each Zebra
+    kernel's custom call after the kernel, whatever function launched it:
+    the names the roofline readers match."""
+    call, args, names = _kernel_cases()[case]
+    hlo = _compile(call, *(jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                           for s, d in args))
+    assert sorted(_CUSTOM_CALL.findall(hlo)) == names
