@@ -184,7 +184,18 @@ def init_token_threshold_net(key: jax.Array, d: int, n_ch_blocks: int,
 # ---------------------------------------------------------------------------
 
 def _block_reduce_max_nchw(x: jax.Array, b: int) -> jax.Array:
-    """(B,C,H,W) -> per-block max (B,C,H//b,W//b). H,W must divide by b."""
+    """(B,C,H,W) -> per-block max|x| (B,C,H//b,W//b). H,W must divide by b.
+
+    One windowed max, which XLA fuses with the threshold compare that
+    reads it: no block max is written."""
+    return jax.lax.reduce_window(jnp.abs(x), -jnp.inf, jax.lax.max,
+                                 (1, 1, b, b), (1, 1, b, b), "VALID")
+
+
+def _block_reduce_max_nchw_grad(x: jax.Array, b: int) -> jax.Array:
+    """``_block_reduce_max_nchw`` as a reshape and ``jnp.max``, whose
+    gradient splits a tied block's among its ties (the windowed max sends
+    it to one element)."""
     B, C, H, W = x.shape
     xb = x.reshape(B, C, H // b, b, W // b, b)
     return jnp.max(jnp.abs(xb), axis=(3, 5))
@@ -198,6 +209,17 @@ def _block_reduce_max_bsd(x: jax.Array, bs: int, bc: int) -> jax.Array:
 
 
 def _expand_mask_nchw(mask_blocks: jax.Array, b: int) -> jax.Array:
+    """(B,C,Hb,Wb) -> (B,C,Hb*b,Wb*b), each value over its b x b block:
+    one broadcast to the map's shape."""
+    B, C, Hb, Wb = mask_blocks.shape
+    m = jnp.broadcast_to(mask_blocks[:, :, :, None, :, None],
+                         (B, C, Hb, b, Wb, b))
+    return m.reshape(B, C, Hb * b, Wb * b)
+
+
+def _expand_mask_nchw_grad(mask_blocks: jax.Array, b: int) -> jax.Array:
+    """``_expand_mask_nchw`` as two ``jnp.repeat``s, whose gradient sums
+    each block row first, then the rows."""
     m = jnp.repeat(mask_blocks, b, axis=2)
     return jnp.repeat(m, b, axis=3)
 
@@ -279,7 +301,14 @@ def zebra_cnn(x: jax.Array, cfg: ZebraConfig, tnet: dict | None = None) -> tuple
         raise ValueError(f"map {H}x{W} not divisible by block {b}")
     tnet = effective_tnet(cfg, tnet)
     require_tnet(cfg, tnet)
-    blockmax = _block_reduce_max_nchw(x, b)                       # (B,C,Hb,Wb)
+    # The soft gate differentiates through the block max and its
+    # expansion: it keeps the reshape/max and repeat forms and their
+    # gradients (tied maxima share one; block sums row by row).
+    if cfg.grad_mode == "soft" and cfg.mode == "train":
+        block_max, expand = _block_reduce_max_nchw_grad, _expand_mask_nchw_grad
+    else:
+        block_max, expand = _block_reduce_max_nchw, _expand_mask_nchw
+    blockmax = block_max(x, b)                                    # (B,C,Hb,Wb)
     surrogate_only = False
     if cfg.mode == "train" and tnet is not None:
         gap = jnp.mean(x, axis=(2, 3)).astype(jnp.float32)        # (B,C) GAP
@@ -296,7 +325,7 @@ def zebra_cnn(x: jax.Array, cfg: ZebraConfig, tnet: dict | None = None) -> tuple
         surrogate_only = cfg.mode == "train"
     keep = (blockmax >= thr_b)
     y = _apply_gate(x, keep, blockmax, thr_b, cfg,
-                    lambda m: _expand_mask_nchw(m, b), surrogate_only)
+                    lambda m: expand(m, b), surrogate_only)
     zero_frac = 1.0 - jnp.mean(keep.astype(jnp.float32))
     n_blocks = C * (H // b) * (W // b)
     if reg is None:

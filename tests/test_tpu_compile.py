@@ -125,31 +125,33 @@ _LOCATIONS = re.compile(
     r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*", re.M)
 
 
+def _r18_forward_hlo(one_chip) -> str:
+    """The compiled text of the ``r18-infer`` forward (ResNet-18 on
+    Tiny-ImageNet, batch 128, 64x64, bfloat16 maps, 4x4 blocks)."""
+    from repro.core import ZebraConfig
+    from repro.models.cnn import build
+    zc = ZebraConfig(t_obj=1.2, block_hw=4, backend="stream",
+                     use_tnet=False, mode="infer")
+    m = build("resnet18", num_classes=200, in_hw=64)
+    v = jax.eval_shape(lambda k: m.init(k, zc), jax.random.PRNGKey(0))
+    v = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), v)
+    x = jax.ShapeDtypeStruct((128, 3, 64, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    return _compile(lambda v, x: m.apply(v, x, False, zc)[0], v, x)
+
+
 def test_scopes_leave_the_chip_program_unchanged(one_chip, as_tpu,
                                                  monkeypatch):
     """The ResNet-18 Tiny-ImageNet forward at batch 128 with bfloat16 maps,
     compiled for a v5e, is the same program with and without its named
     scopes once metadata is stripped: the scopes name ops, and change no
     instruction, fusion or layout."""
-    from repro.core import ZebraConfig
-    from repro.models.cnn import build
-    zc = ZebraConfig(t_obj=1.2, block_hw=4, backend="stream",
-                     use_tnet=False, mode="infer")
-    m = build("resnet18", num_classes=200, in_hw=64)
-
-    def forward_hlo():
-        v = jax.eval_shape(lambda k: m.init(k, zc), jax.random.PRNGKey(0))
-        v = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), v)
-        x = jax.ShapeDtypeStruct((128, 3, 64, 64), jnp.bfloat16,
-                                 sharding=one_chip)
-        return _compile(lambda v, x: m.apply(v, x, False, zc)[0], v, x)
-
-    with_scopes = forward_hlo()
+    with_scopes = _r18_forward_hlo(one_chip)
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     jax.clear_caches()
-    without = forward_hlo()
+    without = _r18_forward_hlo(one_chip)
     assert "/s0b0/zebra.z1/" in with_scopes and "/zebra.z" not in without
     code = [_METADATA.sub("", _LOCATIONS.sub("", h))
             for h in (with_scopes, without)]
@@ -204,3 +206,39 @@ def test_kernel_is_named_in_the_compiled_program(one_chip, as_tpu, case):
     hlo = _compile(call, *(jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                            for s, d in args))
     assert sorted(_CUSTOM_CALL.findall(hlo)) == names
+
+
+_ENTRY_OP = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.+?) "
+                       r"([\w\-]+)\(([^)]*)\)(.*)$", re.M)
+_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
+_SITE = re.compile(r'op_name="[^"]*/zebra\.(z\d+)/')
+
+
+def test_each_cnn_site_is_one_block_max_and_one_broadcast(one_chip, as_tpu):
+    """The ResNet-18 Tiny-ImageNet forward at batch 128 with bfloat16 maps,
+    compiled for a v5e: each of the 17 Zebra sites runs at most two ops of
+    its own, one fusion of the windowed block max with the threshold
+    compare and one broadcast of the keep map to the map's shape. No site
+    reduces a 6-D view of its map (which writes the block max) or
+    broadcasts the keep map through a 5-D intermediate."""
+    hlo = _r18_forward_hlo(one_chip)
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    shape, sites = {}, {}
+    for name, typ, opcode, operands, rest in _ENTRY_OP.findall(entry):
+        array = _ARRAY.match(typ)
+        if array:
+            dims = array.group(2)
+            shape[name] = (array.group(1), len(dims.split(",")) if dims else 0)
+        site = _SITE.search(rest)
+        if site and opcode != "bitcast":
+            sites.setdefault(site.group(1), []).append(
+                (name, opcode, operands.split(",")[0].strip().lstrip("%")))
+    assert sorted(sites) == sorted(f"z{i}" for i in range(17))
+    for site, ops in sites.items():
+        for name, opcode, first in ops:
+            assert not (opcode == "reduce" and shape[first][1] == 6), (
+                site, name)
+            assert not (opcode == "broadcast"
+                        and shape[name] == ("pred", 5)), (site, name)
+        assert len(ops) <= 2, (site, ops)

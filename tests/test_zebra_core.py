@@ -166,3 +166,120 @@ def test_disabled_passthrough():
     x = jax.random.normal(K, (2, 4, 8, 8))
     y, aux = zebra_cnn(x, ZebraConfig(enabled=False))
     np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+# ---------------------------------------------------------------------------
+# The NCHW block gate against its reshape/repeat form
+# ---------------------------------------------------------------------------
+
+def _oracle_block_max(x, b):
+    """The block max as a 6-D reshape and ``jnp.max`` over its windows."""
+    B, C, H, W = x.shape
+    xb = x.reshape(B, C, H // b, b, W // b, b)
+    return jnp.max(jnp.abs(xb), axis=(3, 5))
+
+
+def _oracle_expand(mask_blocks, b):
+    """The block map expanded by two chained ``jnp.repeat``s."""
+    return jnp.repeat(jnp.repeat(mask_blocks, b, axis=2), b, axis=3)
+
+
+def _gate_map(dtype, b, key=K):
+    """(2, 3, 8, 8) map holding all-zero blocks, ties at the block max and
+    negative values: values on a 0.5 grid, so maxima tie; a quarter of the
+    blocks zeroed."""
+    x = jnp.round(2.0 * jax.random.normal(key, (2, 3, 8, 8))) / 2.0
+    dead = jax.random.bernoulli(jax.random.fold_in(key, 1), 0.25,
+                                (2, 3, 8 // b, 8 // b))
+    return (x * _oracle_expand(~dead, b)).astype(dtype)
+
+
+def _bits(a):
+    """The array's bit patterns, so that -0.0 and 0.0 differ."""
+    a = np.asarray(a)
+    return a.view({1: np.uint8, 2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+def _assert_bitwise(a, b):
+    assert a.shape == b.shape and a.dtype == b.dtype
+    np.testing.assert_array_equal(_bits(a), _bits(b))
+
+
+def _reshape_repeat(monkeypatch):
+    """Point ``core.zebra``'s NCHW block helpers at the oracle forms."""
+    from repro.core import zebra
+    for name in ("_block_reduce_max_nchw", "_block_reduce_max_nchw_grad"):
+        monkeypatch.setattr(zebra, name, _oracle_block_max)
+    for name in ("_expand_mask_nchw", "_expand_mask_nchw_grad"):
+        monkeypatch.setattr(zebra, name, _oracle_expand)
+
+
+@pytest.mark.parametrize("b", [4, 2])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_block_gate_helpers_match_reshape_repeat(dtype, b):
+    """The windowed block max and the one-broadcast expansion give the
+    reshape/repeat results bit for bit, ties and dead blocks included."""
+    from repro.core.zebra import _block_reduce_max_nchw, _expand_mask_nchw
+    x = _gate_map(dtype, b)
+    want = _oracle_block_max(x, b)
+    assert bool(jnp.any(want == 0)) and bool(jnp.any(x < 0))
+    _assert_bitwise(_block_reduce_max_nchw(x, b), want)
+    for blocks in (want >= 1.0, want):
+        _assert_bitwise(_expand_mask_nchw(blocks, b),
+                        _oracle_expand(blocks, b))
+
+
+@pytest.mark.parametrize("b", [4, 2])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_masked_maps_match_reshape_repeat(monkeypatch, dtype, b):
+    """``zebra_cnn`` (infer, and constant-threshold and threshold-net
+    training in every gradient mode) and ``zebra_infer_bitmap_nchw`` mask
+    a map bit for bit as the reshape/repeat gate does, and keep the same
+    blocks."""
+    x = _gate_map(dtype, b)
+    tnet = init_threshold_net(jax.random.PRNGKey(3), 3)
+    tnet = dict(tnet, b=tnet["b"] + 1.0)
+    cfg = ZebraConfig(t_obj=1.0, block_hw=b, mode="infer")
+    runs = [(cfg, None)] + [
+        (cfg.replace(mode="train", use_tnet=u, grad_mode=g), tnet)
+        for u in (False, True) for g in ("hard", "ste", "soft")]
+
+    def outputs():
+        out = list(zebra_infer_bitmap_nchw(x, cfg))
+        for c, tn in runs:
+            y, aux = zebra_cnn(x, c, tn)
+            out += [y, aux["zero_frac"]]
+        return out
+
+    got = outputs()
+    keep = got[1]
+    assert 0 < int(jnp.sum(keep)) < keep.size
+    _reshape_repeat(monkeypatch)
+    for g, w in zip(got, outputs(), strict=True):
+        _assert_bitwise(g, w)
+
+
+@pytest.mark.parametrize("grad_mode", ["hard", "ste", "soft"])
+def test_tnet_gradients_match_reshape_repeat(monkeypatch, grad_mode):
+    """Threshold-net training: the gradients to the map and to the net
+    equal those of the reshape/repeat gate (the soft path differentiates
+    through the block max, where ``jnp.max`` splits a tied block's
+    gradient, and through the expansion)."""
+    x = _gate_map(jnp.float32, 4)
+    tnet = init_threshold_net(jax.random.PRNGKey(3), 3)
+    tnet = dict(tnet, b=tnet["b"] + 1.0)
+    cfg = ZebraConfig(t_obj=1.0, block_hw=4, mode="train",
+                      grad_mode=grad_mode, soft_temp=0.5)
+
+    def grads():
+        def loss(xx, tn):
+            y, aux = zebra_cnn(xx, cfg, tn)
+            return jnp.sum(jnp.sin(y)) + aux["reg"]
+        return jax.grad(loss, argnums=(0, 1))(x, tnet)
+
+    got = grads()
+    _reshape_repeat(monkeypatch)
+    want = grads()
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        _assert_bitwise(g, w)
+    assert float(jnp.max(jnp.abs(got[0]))) > 0.0
