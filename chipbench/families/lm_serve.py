@@ -15,9 +15,13 @@ round's start to the last round's end.
 End-to-end metrics (host clock):
 
 * ``tokens_per_s``: served tokens of the window / window seconds;
-* ``ttft_p95_ms``: p95 over the window's requests of round start -> first
-  served token (queueing included: all are due at the round's start);
-* ``itl_p95_ms``: p95 over every gap between consecutive served tokens;
+* ``ttft_p<q>_ms`` for q in ``TTFT_PERCENTILES``: that percentile over
+  the window's requests of round start -> first served token (queueing
+  included: all are due at the round's start). A cell declares the
+  highest q that leaves ten requests beyond it in its smallest window;
+  the count is printed as ``ttft_samples``;
+* ``itl_p95_ms``: p95 over every gap between consecutive served tokens
+  (count ``itl_samples``);
 * ``setup_s``: process start -> first timed request.
 
 Correctness: requests of the first round, drawn from the seed with its
@@ -46,6 +50,7 @@ import numpy as np
 from chipbench import generator
 
 SPAN = "chipbench."
+TTFT_PERCENTILES = (50, 80, 90, 95)
 
 
 def seed_key(seed: int):
@@ -459,7 +464,8 @@ def run(cell) -> dict:
            for a, b in zip(r.token_times, r.token_times[1:])]
     window = t_end - t_begin
     e2e = {"tokens_per_s": n_tok / window,
-           "ttft_p95_ms": float(np.percentile(ttft, 95)),
+           **{f"ttft_p{q}_ms": float(np.percentile(ttft, q))
+              for q in TTFT_PERCENTILES},
            "itl_p95_ms": float(np.percentile(itl, 95)),
            "setup_s": setup_s}
     mem = memory_peak_bytes(int(cell.workload["chips"]))
@@ -480,6 +486,7 @@ def run(cell) -> dict:
                and len(ok) == len(all_reqs))
     notes = {"params_s": round(t_params, 3), "warmup_s": round(t_warm, 3),
              "rounds": k, "window_s": round(window, 3),
+             "ttft_samples": len(ttft), "itl_samples": len(itl),
              "compile_requests_in_window": counter.requests,
              "compiles_in_window": counter.compiles,
              "cache_load_s": round(counter.load_s, 3),
@@ -508,32 +515,43 @@ def passes(checks: dict) -> bool:
                for v in checks.values())
 
 
-def _layer_data(c, eng, spans, done, cell):
-    """Counters of the traced round, and the reduced trace."""
+def round_counters(c, prefills, reqs, p_lo, window_s, pool_s, admits):
+    """Counters of a traced round for the per-layer readers. ``prefills``
+    lists each prefill call as ``(length, FFN zero blocks over all its
+    layers)``; ``reqs`` the round's requests (``prompt_len``, ``out``);
+    ``p_lo`` the engine's least prefill length."""
     from chipbench.metrics.lib import counts
-    from chipbench.metrics.lib import trace as trl
     from repro.serve.bucket import pow2_floor
-    (t0, t1, reqs), = done
     L = c["num_hidden_layers"]
-    f, bs, bc = c["intermediate_size"], spans.bs, spans.bc
+    f = c["intermediate_size"]
+    z = c["served"]["zebra"]
     calls = []
-    for pb, aux, kv0 in spans.prefills:
-        nb = L * (pb // bs) * (f // bc)
-        ffn_zero = float(aux.zf_blocks) - float(kv0)
+    for pb, ffn_zero in prefills:
+        nb = L * (pb // z["block_seq"]) * (f // z["block_ch"])
         calls.append({"M": pb, "n_live": nb - ffn_zero, "n_blocks": nb})
     flops = 0.0
     for r in reqs:
         P = r.prompt_len
         pb = pow2_floor(P)
-        pb = pb if pb >= eng.p_lo else 0
+        pb = pb if pb >= p_lo else 0
         fed = min(pb, P - 1)
         flops += counts.lm_request_flops(c, pb, fed, P + len(r.out) - 1)
-    counters = {"window_s": t1 - t0, "model_flops": flops,
-                "pool_s": spans.pool_s, "admits": spans.admits,
-                "prefill_calls": calls, "layers": L,
-                "ffn_zero_frac": 1.0 - (sum(x["n_live"] for x in calls)
-                                        / max(sum(x["n_blocks"]
-                                                  for x in calls), 1))}
+    return {"window_s": window_s, "model_flops": flops,
+            "pool_s": pool_s, "admits": admits,
+            "prefill_calls": calls, "layers": L,
+            "ffn_zero_frac": 1.0 - (sum(x["n_live"] for x in calls)
+                                    / max(sum(x["n_blocks"]
+                                              for x in calls), 1))}
+
+
+def _layer_data(c, eng, spans, done, cell):
+    """Counters of the traced round, and the reduced trace."""
+    from chipbench.metrics.lib import trace as trl
+    (t0, t1, reqs), = done
+    counters = round_counters(
+        c, [(pb, float(aux.zf_blocks) - float(kv0))
+            for pb, aux, kv0 in spans.prefills],
+        reqs, eng.p_lo, t1 - t0, spans.pool_s, spans.admits)
     tdir = cell.out_dir / f"trace_{cell.name}_{cell.seed}"
     rec = trl.extract(trl.find_xplane(str(tdir)))
     shutil.rmtree(tdir, ignore_errors=True)

@@ -3,11 +3,17 @@ the harness's look for a chip is skipped, the rest of the run is the real
 one at toy sizes. Faults a cell can have: a decode step that returns its
 state unchanged, a token or an answer altered where it is produced, half
 of a batch left out, and for a served LM the pool's page-in handing back
-zeros. (No cell spans chips, so no exchange can be left out.)"""
+zeros, and a served token one place below the best where the best leads
+by more than a non-zero ``max_logit_gap`` limit. (No cell spans chips,
+so no exchange can be left out.)"""
+import json
+import time
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+from chipbench import bench
 from chipbench.families import cnn_infer
 from chipbench.tests import tiny
 
@@ -48,6 +54,43 @@ def test_lm_fault_is_not_correct(monkeypatch, tmp_path, fault, number):
     res = tiny.run("tiny-chat", 11, out_dir=tmp_path)
     c = res["checks"][number]
     assert not res["correct"] and c["value"] > c["limit"]
+
+
+def _planted_decode(monkeypatch, margin):
+    """The decode step serves the second-best token of its own logits
+    wherever the best leads by more than ``margin``."""
+    import repro.serve.engine as engine
+
+    def make(model, mesh, temperature=0.0):
+        def planted(params, token, state, pos, key):
+            logits, state = model.decode_step(params, token, state, pos)
+            top, idx = jax.lax.top_k(logits.astype(jnp.float32), 2)
+            nxt = jnp.where(top[:, 0] - top[:, 1] > margin, idx[:, 1],
+                            idx[:, 0])
+            return nxt.astype(jnp.int32)[:, None], state
+        return planted
+    monkeypatch.setattr(engine, "make_decode_slotted", make)
+
+
+@pytest.mark.parametrize("limit", [0.05, 1.0])
+def test_lm_token_one_place_down_is_not_correct(monkeypatch, tmp_path, limit):
+    """A limit on ``max_logit_gap`` above 0 still fails a token one place
+    below the best by more than twice the limit; the prefill logits and
+    the cache, which the planted tokens do not touch, still pass."""
+    _planted_decode(monkeypatch, 2 * limit)
+    (tmp_path / "limits").mkdir()
+    (tmp_path / "limits" / "tiny-chat.json").write_text(json.dumps(
+        {**tiny.cell("tiny-chat").limits, "max_logit_gap": limit}))
+    res = bench.run_cell("tiny-chat", 11, 0.5, False, t_start=time.time(),
+                         bench=tiny.spec(), require_chip=False,
+                         out_dir=tmp_path, config_dir=tiny.DATA,
+                         dirs=(tmp_path,) + tiny.DIRS)
+    c = res["checks"]
+    assert not res["correct"]
+    assert c["max_logit_gap"]["limit"] == limit
+    assert c["max_logit_gap"]["value"] > limit
+    for k in ("prefill_logit_err", "kv_err"):
+        assert c[k]["value"] <= c[k]["limit"]
 
 
 def _broken_forward(monkeypatch, fault):
