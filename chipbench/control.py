@@ -65,18 +65,44 @@ def lm_seed(cell, seed: int, check_weights: bool, with_control: bool = True
     t0 = time.time()
     eng.run(reqs)
     out["round_s"] = time.time() - t0
-    got = capture.host(checked, eng.p_lo)
+    got = capture.host(checked)
+    rows = drv.ref_rows(tr, eng._prefill_bucket)
     del eng, params, model, capture
     gc.collect()
-    numbers, lead = drv.readings(
-        c, key, got,
-        CONTROL_MODE[c["served"]["dtype"]] if with_control else None)
-    out.update(numbers, checked=len(got), tokens=sum(r.n for r in got),
+    t0 = time.time()
+    out["program"], lead = drv.readings(c, key, got, rows)
+    out["reference_s"] = time.time() - t0
+    if with_control:
+        ctrl, taken = lm_control(c, key, got,
+                                 CONTROL_MODE[c["served"]["dtype"]], rows)
+        out["control"], _ = drv.readings(c, key, ctrl, rows, taken)
+    out.update(checked=len(got), tokens=sum(r.n for r in got),
                reference_least_lead=lead,
                repeat_rate=float(np.mean([float(np.mean(
                    r.ids[r.P:r.P + r.n] == r.ids[r.P - 1:r.P + r.n - 1]))
                    for r in got])))
     return out
+
+
+def lm_control(c: dict, key, checked: list, mode: str, rows):
+    """The control's output in the program's place, for each checked
+    request: the reference's own pass at precision ``mode`` over the same
+    sequence, holding what the program's output holds (the prefill's
+    last-row logits, the cache rows, and as served tokens the ones its
+    own logits put first); and the FFN decisions that pass took, per
+    request (``readings``' ``taken``)."""
+    import dataclasses
+
+    from chipbench.reference import starcoder2 as ref
+    out, taken = list(checked), [None] * len(checked)
+    for i, res in ref.forward(c, c["served"]["zebra"], key,
+                              [r.seq for r in checked], kmax=rows[0],
+                              lbmax=rows[1], mode=mode):
+        out[i] = dataclasses.replace(checked[i], logits=res["pre"],
+                                     k=res["k"], v=res["v"],
+                                     tokens=res["top"])
+        taken[i] = res["keep"]
+    return out, taken
 
 
 def cnn_seed(cell, seed: int, check_weights: bool, with_control: bool = True

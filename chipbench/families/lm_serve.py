@@ -31,12 +31,17 @@ logits, and at retirement the request's cache lane as the decode program
 left it (the prefix after the pool's page-out/page-in round trip, then
 every decoded position). After the window the program's state is freed
 and ``readings`` compares those, and the served tokens, with the plain
-reference (``reference/starcoder2.py``); each number the cell's limits
-file names is compared with its limit.
+reference (``reference/starcoder2.py``) in its decision-matched pass:
+the reference takes the gate decisions the served path took at every
+site, layer and block, so the errors measure the arithmetic, and the
+decisions are judged on their own (``flip_share``, ``flip_margin``).
+Each number the cell's limits file names is compared with its limit.
 
-With ``trace``, set-up is the same, one round is served under the
-profiler with the harness's spans around the engine's layers, and the
-per-layer readers get the trace plus these counters.
+With ``trace``, set-up is the same, and one round is served with the
+harness's spans around the engine's layers; the profiler records the
+round's first ``trace_ticks`` engine ticks (the traffic file's number),
+and the per-layer readers get that trace plus counters of the same
+ticks.
 """
 from __future__ import annotations
 
@@ -128,50 +133,81 @@ class _Rids:
 class _Spans:
     """Host spans around the engine's layers (``jax.profiler``
     annotations on the trace's clock) and the counters the per-layer
-    readers take; installed on one engine instance for a traced round."""
+    readers take, for one traced slice: from ``start`` (the round's
+    start) through ``ticks`` engine ticks (decode dispatches), when the
+    profiler stops. Installed on one engine instance."""
 
-    def __init__(self, eng):
+    def __init__(self, eng, ticks: int, tdir):
         import jax
         self.jax = jax
-        self.reset()
+        self.ticks, self.tdir = int(ticks), str(tdir)
+        self.on = False
+        self.t0 = self.t1 = None
+        self.pool_s = 0.0
+        self.admits = 0
+        self.prefills = []          # (Pb, aux, kv zero blocks) per call
+        self.decode_pos = []        # each decoded token's position
+        self.steps = 0              # ticks in the slice
         self._kv_zero = jax.jit(_kv_zero_blocks, static_argnames=("bs", "bc"))
         self.bs, self.bc = eng.cfg.zebra_block_seq, eng.cfg.zebra_block_ch
         pool = eng.pool
         self._wrap(eng, "_schedule", "schedule")
-        self._wrap(eng, "_step", "decode_step")
         self._wrap(eng, "_retire", "retire")
         self._wrap(eng, "report", "report")
         self._wrap(eng, "_admit_tree", "admit", count=True)
         self._wrap(pool, "page_out", "pool.page_out", timed=True)
         self._wrap(pool, "page_in", "pool.page_in", timed=True)
-        prefill = eng._prefill
+        self._wrap(eng, "_step", "decode_step")
+        step, prefill = eng._step, eng._prefill
+
+        def traced_step(now):
+            on = self.on
+            if on:
+                self.decode_pos += [r.pos for r in eng._lanes if r is not None]
+            out = step(now)
+            if on:
+                self.steps += 1
+                if self.steps >= self.ticks:
+                    self.stop()
+            return out
 
         def traced_prefill(params, prompt):
             with jax.profiler.TraceAnnotation(SPAN + "prefill"):
                 out = prefill(params, prompt)
-            _, (caches, _), aux = out
-            self.prefills.append((int(prompt.shape[1]), aux,
-                                  self._kv_zero(caches, bs=self.bs,
-                                                bc=self.bc)))
+            if self.on:
+                _, (caches, _), aux = out
+                self.prefills.append((int(prompt.shape[1]), aux,
+                                      self._kv_zero(caches, bs=self.bs,
+                                                    bc=self.bc)))
             return out
-        eng._prefill = traced_prefill
+        eng._step, eng._prefill = traced_step, traced_prefill
 
-    def reset(self) -> None:
-        self.pool_s = 0.0
-        self.admits = 0
-        self.prefills = []          # (Pb, aux, kv zero blocks) per call
+    def start(self) -> None:
+        self.jax.profiler.start_trace(self.tdir)
+        self._window = self.jax.profiler.TraceAnnotation(SPAN + "window")
+        self._window.__enter__()
+        self.on, self.t0 = True, time.time()
+
+    def stop(self) -> None:
+        """End the slice (after its last tick, or with the round)."""
+        if not self.on:
+            return
+        self._window.__exit__(None, None, None)
+        self.on, self.t1 = False, time.time()
+        self.jax.profiler.stop_trace()
 
     def _wrap(self, obj, attr, name, timed=False, count=False):
         fn = getattr(obj, attr)
         jax = self.jax
 
         def wrapped(*a, **k):
-            if count:
+            on = self.on
+            if count and on:
                 self.admits += 1
             t0 = time.perf_counter()
             with jax.profiler.TraceAnnotation(SPAN + name):
                 out = fn(*a, **k)
-            if timed:
+            if timed and on:
                 self.pool_s += time.perf_counter() - t0
             return out
         setattr(obj, attr, wrapped)
@@ -188,6 +224,7 @@ class _Capture:
         self.rids = set(rids)
         self.logits, self.lanes = {}, {}
         self._cur = None
+        self._prefill_len = eng._prefill_bucket
         admit, prefill, retire = eng._admit_tree, eng._prefill, eng._retire
 
         def admit_tree(r):
@@ -212,16 +249,15 @@ class _Capture:
                                                       prefill_kept,
                                                       retire_kept)
 
-    def host(self, reqs, p_lo: int) -> list:
+    def host(self, reqs) -> list:
         """The checked requests that finished, with what was kept, on the
         host (``Checked``)."""
-        from repro.serve.bucket import pow2_floor
         out = []
         for r in reqs:
             if r.rid not in self.logits or r.rid not in self.lanes:
                 continue
             P, n = r.prompt_len, len(r.out)
-            pb = pow2_floor(P) if pow2_floor(P) >= p_lo else 0
+            pb = self._prefill_len(P)
             end = P + n - 1
             (run,) = self.lanes[r.rid]          # one run of attention layers
             kv = [np.asarray(sub[x][:, 0, :end]) for sub in run.values()
@@ -233,7 +269,8 @@ class _Capture:
                                     np.asarray(r.out, np.int32)]),
                 P=P, pb=pb, fed=min(pb, P - 1), n=n,
                 logits=np.asarray(self.logits[r.rid], np.float32)[0],
-                k=kv[0], v=kv[1]))
+                k=kv[0].astype(np.float32), v=kv[1].astype(np.float32),
+                tokens=np.asarray(r.out, np.int32)))
         self.logits.clear()
         self.lanes.clear()
         return out
@@ -243,8 +280,10 @@ class _Capture:
 class Checked:
     """One checked request: its sequence (prompt then served tokens), the
     prompt and prefill lengths, the first position decoded alone, the
-    served count, the prefill's last-row logits ``(V,)`` and the cache
-    rows ``(layers, P + n - 1, nkv, hd)`` the decode program left."""
+    served count; and what the output under test holds for it: the
+    prefill's last-row logits ``(V,)``, the cache rows ``(layers, P + n -
+    1, nkv, hd)`` the decode program left, and the ``n`` tokens served
+    (for the program, the sequence's own)."""
     ids: np.ndarray
     P: int
     pb: int
@@ -253,10 +292,15 @@ class Checked:
     logits: np.ndarray
     k: np.ndarray
     v: np.ndarray
+    tokens: np.ndarray
 
     @property
     def seq(self):
         return self.ids, self.P, self.pb, self.fed, self.n
+
+    @property
+    def output(self):
+        return self.k, self.v, self.logits, self.tokens
 
 
 def checked_requests(reqs, tr, seed) -> list:
@@ -277,65 +321,85 @@ def _rel(got, want) -> float:
     return float(np.linalg.norm(got - want) / np.linalg.norm(want))
 
 
-def _rows(kv, fed: int, end: int):
-    """A server cache's K and V rows ``[0, end)`` from the reference's
-    (gated prefill rows before ``fed``, decoded rows from it)."""
-    kA, vA, kB, vB = (np.asarray(t) for t in kv)
-    return (np.concatenate([kA[:fed], kB[:end - fed]]),
-            np.concatenate([vA[:fed], vB[:end - fed]]))
+def ref_rows(tr: dict, prefill_len) -> tuple[int, int]:
+    """The rows the reference pads every request's prefix and decoded
+    part to (``kmax``, ``lbmax``): the longest prefill of the traffic's
+    sizes by the engine's own rule ``prefill_len`` (``ServeEngine.
+    _prefill_bucket``), and the bucket of the most rows a request decodes
+    one at a time."""
+    from repro.serve.bucket import pow2_bucket
+    kmax, lb = 8, 1
+    for P, n in generator.round_sizes(tr):
+        pb = prefill_len(P)
+        kmax = max(kmax, pb)
+        lb = max(lb, P + n - 1 - min(pb, P - 1))
+    return kmax, pow2_bucket(lb, lo=128)
 
 
-NUMBERS = ("prefill_logit_err", "kv_err", "max_logit_gap")
+NUMBERS = ("prefill_logit_err", "kv_err", "max_logit_gap", "flip_share",
+           "flip_margin")
 
 
-def readings(c: dict, key, checked: list, control: str | None = None):
-    """The numbers the check compares, for the program's output and, with
-    ``control`` (a ``precision`` mode), for the reference at that mode put
-    in the program's place; and the reference's least lead of its best
+def readings(c: dict, key, checked: list, rows: tuple[int, int],
+             taken=None):
+    """The numbers the check compares for an output under test (the
+    program's, or the control's in its place), against the reference's
+    decision-matched pass; and the reference's least lead of its best
     logit over the second at a served position.
+
+    ``taken`` (per request, the FFN decisions the output is known to
+    have taken, as the reference's ``res["keep"]``) adds
+    ``recovered_miss``: the blocks whose decision shows in the output
+    and was recovered otherwise, of ``recovered_shown``.
 
     * ``prefill_logit_err``: the prefill's last-row logits,
       ||got - ref|| / ||ref||, worst request;
     * ``kv_err``: the cache's K or V rows at every position of a request,
-      ||got - ref|| / ||ref||, worst layer, K or V and request;
+      prefilled and decoded, ||got - ref|| / ||ref||, worst layer, K or V
+      and request;
     * ``max_logit_gap``: the widest gap of a served token below the
-      reference's best logit at its position (for the control, of the
-      token that its own logits put first).
+      reference's best logit at its position;
+    * ``flip_share``: at each Zebra site, over its layers and the checked
+      requests, the share of blocks whose decision the output took
+      differs from the threshold's on the decision-matched map; worst
+      site (a layer alone can show few blocks: the last shows only the
+      prefill's last row group);
+    * ``flip_margin``: the largest |block max - T_obj| / T_obj of such a
+      block.
     """
-    import jax.numpy as jnp
-
     from chipbench.reference import starcoder2 as ref
-    modes = ("f32",) + ((control,) if control else ())
-    who = ("program",) + (("control",) if control else ())
-    out = {w: dict.fromkeys(NUMBERS, 0.0) for w in who}
-
-    def worst(w, k, v):
-        out[w][k] = max(out[w][k], v)
-
-    def on_kv(i, li, kv):
-        r = checked[i]
-        end = r.P + r.n - 1
-        wk, wv = _rows(kv["f32"], r.fed, end)
-        got = {"program": (r.k[li], r.v[li])}
-        if control:
-            got["control"] = _rows(kv[control], r.fed, end)
-        for w, (gk, gv) in got.items():
-            worst(w, "kv_err", max(_rel(gk, wk), _rel(gv, wv)))
-
+    out = dict.fromkeys(NUMBERS, 0.0)
+    flips: dict = {}
     lead = float("inf")
-    for i, lg in ref.forward(c, c["served"]["zebra"], key,
-                             [r.seq for r in checked], modes=modes,
-                             on_kv=on_kv):
+    miss = shown = 0
+    for i, res in ref.forward(c, c["served"]["zebra"], key,
+                              [r.seq for r in checked], kmax=rows[0],
+                              lbmax=rows[1],
+                              outputs=[r.output for r in checked]):
         r = checked[i]
-        pre, served = lg["f32"]
-        got = {"program": (r.logits, jnp.asarray(r.ids[r.P:r.P + r.n]))}
-        if control:
-            cpre, cserved = lg[control]
-            got["control"] = (cpre, jnp.argmax(cserved, -1).astype(jnp.int32))
-        for w, (logits, tok) in got.items():
-            worst(w, "prefill_logit_err", _rel(logits, pre))
-            worst(w, "max_logit_gap", float(jnp.max(ref.gap(served, tok))))
-        lead = min(lead, float(jnp.min(ref.margin(served))))
+        out["prefill_logit_err"] = max(out["prefill_logit_err"],
+                                       _rel(r.logits, res["pre"]))
+        for d2k, r2k, d2v, r2v in res["kv"]:
+            out["kv_err"] = max(out["kv_err"], float(np.sqrt(d2k / r2k)),
+                                float(np.sqrt(d2v / r2v)))
+        out["max_logit_gap"] = max(out["max_logit_gap"],
+                                   float(np.max(res["gap"])))
+        for site, per_layer in res["flips"].items():
+            for _, n_ev, n_flip, worst in per_layer:
+                acc = flips.setdefault(site, [0.0, 0.0])
+                acc[0] += n_ev
+                acc[1] += n_flip
+                out["flip_margin"] = max(out["flip_margin"], worst)
+        lead = min(lead, float(np.min(res["lead"])))
+        for got_, ev, want in zip(res["keep"], res["ev"],
+                                  taken[i] if taken else ()):
+            for k, e, t in zip(got_, ev, want):
+                miss += int(np.sum((k != t) & e))
+                shown += int(np.sum(e))
+    if taken:
+        out["recovered_miss"], out["recovered_shown"] = miss, shown
+    out["flip_share"] = max((f / b for b, f in flips.values() if b),
+                            default=0.0)
     return out, lead
 
 
@@ -420,14 +484,13 @@ def run(cell) -> dict:
                       validation="off", temperature=0.0,
                       seed=cell.seed % 2 ** 31, queue_bound=0)
     rids = _Rids()
-    spans = _Spans(eng) if cell.trace else None
+    tdir = cell.out_dir / f"trace_{cell.name}_{cell.seed}"
+    spans = _Spans(eng, tr["trace_ticks"], tdir) if cell.trace else None
     t_w = time.time()
     _warm_up(eng, rids, tr, sizes, vocab, cell.seed)
     t_warm = time.time() - t_w
 
     # -- the window
-    if spans is not None:
-        spans.reset()
     first = rids.requests(generator.closed_round(
         tr, vocab=vocab, seed=cell.seed, round_index=0))
     checked = checked_requests(first, tr, cell.seed)
@@ -441,11 +504,9 @@ def run(cell) -> dict:
             tr, vocab=vocab, seed=cell.seed, round_index=k))
         if cell.trace:
             cell.out_dir.mkdir(parents=True, exist_ok=True)
-            tdir = cell.out_dir / f"trace_{cell.name}_{cell.seed}"
-            jax.profiler.start_trace(str(tdir))
-            with jax.profiler.TraceAnnotation(SPAN + "window"):
-                t0, t1 = _serve_round(eng, reqs)
-            jax.profiler.stop_trace()
+            spans.start()
+            t0, t1 = _serve_round(eng, reqs)
+            spans.stop()
         else:
             t0, t1 = _serve_round(eng, reqs)
         done.append((t0, t1, reqs))
@@ -472,16 +533,18 @@ def run(cell) -> dict:
 
     counters, rec, breakdown = {}, None, {}
     if cell.trace:
-        counters, rec, breakdown = _layer_data(c, eng, spans, done, cell)
+        counters, rec, breakdown = _layer_data(c, spans, tdir)
 
     # -- correctness: free the program's state, then the reference
-    got = capture.host([r for r in checked if r.status == "done"], eng.p_lo)
+    got = capture.host([r for r in checked if r.status == "done"])
+    rows = ref_rows(tr, eng._prefill_bucket)
+    spans_ticks = spans.steps if spans is not None else 0
     del eng, params, model, spans, capture
     gc.collect()
     t_r = time.time()
-    numbers, lead = readings(c, key, got)
+    numbers, lead = readings(c, key, got, rows)
     t_ref = time.time() - t_r
-    checks = _checks(numbers["program"], cell.limits)
+    checks = _checks(numbers, cell.limits)
     correct = (passes(checks) and len(got) == len(checked)
                and len(ok) == len(all_reqs))
     notes = {"params_s": round(t_params, 3), "warmup_s": round(t_warm, 3),
@@ -497,6 +560,8 @@ def run(cell) -> dict:
              "reference_least_lead": round(lead, 5)}
     if counters:
         notes["ffn_zero_frac"] = round(counters["ffn_zero_frac"], 5)
+        notes["traced_ticks"] = spans_ticks
+        notes["traced_s"] = round(counters["window_s"], 3)
     return {"correct": correct, "attempted": len(all_reqs),
             "failed": len(all_reqs) - len(ok), "e2e": e2e, "checks": checks,
             "trace": rec, "counters": counters, "breakdown": breakdown,
@@ -515,13 +580,12 @@ def passes(checks: dict) -> bool:
                for v in checks.values())
 
 
-def round_counters(c, prefills, reqs, p_lo, window_s, pool_s, admits):
-    """Counters of a traced round for the per-layer readers. ``prefills``
+def round_counters(c, prefills, decode_pos, window_s, pool_s, admits):
+    """Counters of a traced slice for the per-layer readers. ``prefills``
     lists each prefill call as ``(length, FFN zero blocks over all its
-    layers)``; ``reqs`` the round's requests (``prompt_len``, ``out``);
-    ``p_lo`` the engine's least prefill length."""
+    layers)``; ``decode_pos`` the position of every token the decode
+    program processed (teacher-forced or generated)."""
     from chipbench.metrics.lib import counts
-    from repro.serve.bucket import pow2_floor
     L = c["num_hidden_layers"]
     f = c["intermediate_size"]
     z = c["served"]["zebra"]
@@ -529,13 +593,8 @@ def round_counters(c, prefills, reqs, p_lo, window_s, pool_s, admits):
     for pb, ffn_zero in prefills:
         nb = L * (pb // z["block_seq"]) * (f // z["block_ch"])
         calls.append({"M": pb, "n_live": nb - ffn_zero, "n_blocks": nb})
-    flops = 0.0
-    for r in reqs:
-        P = r.prompt_len
-        pb = pow2_floor(P)
-        pb = pb if pb >= p_lo else 0
-        fed = min(pb, P - 1)
-        flops += counts.lm_request_flops(c, pb, fed, P + len(r.out) - 1)
+    flops = (sum(counts.lm_prefill_flops(c, x["M"]) for x in calls)
+             + sum(counts.lm_decode_flops(c, p) for p in decode_pos))
     return {"window_s": window_s, "model_flops": flops,
             "pool_s": pool_s, "admits": admits,
             "prefill_calls": calls, "layers": L,
@@ -544,15 +603,13 @@ def round_counters(c, prefills, reqs, p_lo, window_s, pool_s, admits):
                                               for x in calls), 1))}
 
 
-def _layer_data(c, eng, spans, done, cell):
-    """Counters of the traced round, and the reduced trace."""
+def _layer_data(c, spans, tdir):
+    """Counters of the traced slice, and the reduced trace."""
     from chipbench.metrics.lib import trace as trl
-    (t0, t1, reqs), = done
     counters = round_counters(
         c, [(pb, float(aux.zf_blocks) - float(kv0))
             for pb, aux, kv0 in spans.prefills],
-        reqs, eng.p_lo, t1 - t0, spans.pool_s, spans.admits)
-    tdir = cell.out_dir / f"trace_{cell.name}_{cell.seed}"
+        spans.decode_pos, spans.t1 - spans.t0, spans.pool_s, spans.admits)
     rec = trl.extract(trl.find_xplane(str(tdir)))
     shutil.rmtree(tdir, ignore_errors=True)
     trl.save(rec, f"{tdir}.json.gz")

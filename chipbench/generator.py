@@ -3,9 +3,11 @@ under ``chipbench/traffic/``; this module turns it and a seed into work.
 
 Lengths are drawn by stratified quantiles: a round of ``n`` requests
 takes the values of the length distribution at ``(i + 0.5) / n`` for
-``i < n``, and the seed only permutes them and draws the token ids.
-Every seed therefore sends the same multiset of sizes in another
-order, so runs with different seeds do the same amount of work.
+``i < n``. Prompt and output lengths are paired once, by a permutation
+drawn from a constant key (``round_sizes``); the seed only permutes the
+whole pairs and draws the token ids. Every seed therefore sends the same
+(prompt, output) pairs in another order, so runs with different seeds
+do the same amount of work.
 
 Length specs (all inclusive integer bounds):
 
@@ -61,27 +63,30 @@ def stratified(spec: dict, n: int) -> list[int]:
 def closed_round(traffic: dict, *, vocab: int, seed: int,
                  round_index: int) -> list[tuple[np.ndarray, int]]:
     """One closed round: ``[(prompt int32 ids, max_new), ...]``, all due
-    at the round's start. ``round_index`` < 0 is warm-up traffic: the
-    same sizes, other ids and order."""
-    n = int(traffic["round_requests"])
+    at the round's start: the pairs of ``round_sizes`` in an order drawn
+    from the seed, with token ids drawn from it. ``round_index`` < 0 is
+    warm-up traffic: the same pairs, other ids and order."""
+    pairs = round_sizes(traffic)
     rng = rng_for(seed, 1, round_index)
-    parts = [np.asarray(stratified(p, n))[rng.permutation(n)]
-             for p in traffic["prompt"]["parts"]]
-    outs = np.asarray(stratified(traffic["output"], n))[rng.permutation(n)]
     reqs = []
-    for i in range(n):
-        plen = int(sum(int(p[i]) for p in parts))
+    for i in rng.permutation(len(pairs)):
+        plen, max_new = pairs[int(i)]
         prompt = rng.integers(0, vocab, size=plen, dtype=np.int64)
-        reqs.append((prompt.astype(np.int32), int(outs[i])))
+        reqs.append((prompt.astype(np.int32), max_new))
     return reqs
 
 
 def round_sizes(traffic: dict) -> list[tuple[int, int]]:
-    """The multiset of (prompt length, output length) pairs every round
-    draws from, as ``closed_round`` would pair them for seed 0, round 0
-    (pairing varies by seed; the two marginals do not)."""
-    return [(int(p.shape[0]), m) for p, m in
-            closed_round(traffic, vocab=2, seed=0, round_index=0)]
+    """The (prompt length, output length) pairs of every round, whatever
+    the seed: each length spec's stratified values, paired by
+    permutations drawn from a constant key."""
+    n = int(traffic["round_requests"])
+    rng = rng_for(0, 1, 0)
+    parts = [np.asarray(stratified(p, n))[rng.permutation(n)]
+             for p in traffic["prompt"]["parts"]]
+    outs = np.asarray(stratified(traffic["output"], n))[rng.permutation(n)]
+    return [(int(sum(int(p[i]) for p in parts)), int(outs[i]))
+            for i in range(n)]
 
 
 def staged_sample(n_done: int, seed: int, k: int,

@@ -15,8 +15,10 @@ def test_lm_control_fails_where_the_program_passes(seed):
     assert all(row["weights_bitwise"])
     for k, lim in cell.limits.items():
         assert row["program"][k] <= lim
-    assert row["control"]["prefill_logit_err"] > cell.limits["prefill_logit_err"]
-    assert row["control"]["kv_err"] > cell.limits["kv_err"]
+    for k in ("prefill_logit_err", "kv_err", "flip_margin"):
+        assert row["control"][k] > cell.limits[k], k
+    assert row["control"]["recovered_shown"] > 0
+    assert row["control"]["recovered_miss"] == 0
 
 
 def test_cnn_control_fails_where_the_program_passes():

@@ -3,8 +3,10 @@ the harness's look for a chip is skipped, the rest of the run is the real
 one at toy sizes. Faults a cell can have: a decode step that returns its
 state unchanged, a token or an answer altered where it is produced, half
 of a batch left out, and for a served LM the pool's page-in handing back
-zeros, and a served token one place below the best where the best leads
-by more than a non-zero ``max_logit_gap`` limit. (No cell spans chips,
+zeros (the check takes the all-zero cache as the K/V gate's decisions,
+so what fails is those decisions: every block, far above the threshold,
+dropped), and a served token one place below the best where the best
+leads by more than a non-zero ``max_logit_gap`` limit. (No cell spans chips,
 so no exchange can be left out.)"""
 import json
 import time
@@ -45,7 +47,7 @@ def _broken_page_in(monkeypatch):
 
 @pytest.mark.parametrize("fault,number", [("state", "kv_err"),
                                           ("token", "max_logit_gap"),
-                                          ("page_in", "kv_err")])
+                                          ("page_in", "flip_share")])
 def test_lm_fault_is_not_correct(monkeypatch, tmp_path, fault, number):
     if fault == "page_in":
         _broken_page_in(monkeypatch)

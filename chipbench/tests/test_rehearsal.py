@@ -8,6 +8,7 @@ import shutil
 import subprocess
 import sys
 
+from chipbench.metrics.lib import trace
 from chipbench.tests import tiny
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -19,15 +20,29 @@ def test_lm_cell_on_cpu(tmp_path):
     assert res["metrics"] == {}
     assert res["device"]["platform"] == "cpu"
     assert list(res["checks"]) == ["prefill_logit_err", "kv_err",
-                                   "max_logit_gap"]
+                                   "max_logit_gap", "flip_share",
+                                   "flip_margin"]
     assert list(res)[-1] == "checks"
 
 
 def test_lm_cell_traced_on_cpu(tmp_path):
+    """A traced run serves its whole round but profiles only its first
+    ``trace_ticks`` engine ticks: the trace holds that many decode
+    steps, and the round goes on past them."""
     res = tiny.run("tiny-chat", 5, trace=True, out_dir=tmp_path)
     assert res["correct"] and res["metrics"] == {}
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
-    assert list(tmp_path.glob("trace_tiny-chat_5.json.gz"))
+    (path,) = tmp_path.glob("trace_tiny-chat_5.json.gz")
+    rec = trace.load(path)
+    steps = [s for s in rec["spans"]
+             if s[0] == trace.SPAN_PREFIX + "decode_step"]
+    traffic = tiny.cell("tiny-chat").traffic
+    ticks = traffic["trace_ticks"]
+    assert traffic["output"]["lo"] > ticks   # each request outlasts the slice
+    assert len(steps) == ticks
+    assert all(rec["window"][0] <= s[1] and s[1] + s[2] <= rec["window"][1]
+               for s in steps)
+    assert any(s[0] == trace.SPAN_PREFIX + "prefill" for s in rec["spans"])
 
 
 def test_cnn_cell_on_cpu(tmp_path):

@@ -7,8 +7,6 @@ TTFT percentile."""
 import json
 import math
 import pathlib
-import types
-
 import pytest
 
 from chipbench import bench, peaks
@@ -35,10 +33,9 @@ def _recorded_round(config):
     L, z = config["num_hidden_layers"], config["served"]["zebra"]
     n_blocks = L * (512 // z["block_seq"]) * (config["intermediate_size"]
                                               // z["block_ch"])
-    req = types.SimpleNamespace(prompt_len=512, out=[0] * 6)
     counters = lm_serve.round_counters(
-        config, [(512, 0.25 * n_blocks)], [req], 8, tr.window_seconds(rec),
-        pool_s, 1)
+        config, [(512, 0.25 * n_blocks)], range(512, 518),
+        tr.window_seconds(rec), pool_s, 1)
     return rec, counters
 
 

@@ -35,13 +35,24 @@ def test_seeds_change_order_not_sizes(name):
     a = g.closed_round(tr, vocab=100, seed=1, round_index=0)
     b = g.closed_round(tr, vocab=100, seed=2 ** 40 + 7, round_index=0)
     c = g.closed_round(tr, vocab=100, seed=1, round_index=1)
-    # each part's sizes are one multiset in another order, so the total
-    # prompt tokens and the output lengths agree; the pairing does not
+    # the same (prompt, output) pairs in another order
     totals = [sum(len(p) for p, _ in r) for r in (a, b, c)]
     outs = [sorted(m for _, m in r) for r in (a, b, c)]
     assert totals[0] == totals[1] == totals[2]
     assert outs[0] == outs[1] == outs[2]
     assert [len(p) for p, _ in a] != [len(p) for p, _ in b]
+
+
+@pytest.mark.parametrize("name", ["code", "chat"])
+def test_every_seed_serves_the_same_pairs(name):
+    """The (prompt, output) pairing is fixed: every round of every seed
+    serves the same pairs, so the same prompt and output tokens."""
+    tr = load(name)
+    want = sorted(g.round_sizes(tr))
+    for seed, k in ((1, 0), (BIG, 0), (2 ** 40 + 7, 3), (5, -1)):
+        r = g.closed_round(tr, vocab=100, seed=seed, round_index=k)
+        assert sorted((len(p), m) for p, m in r) == want
+    assert len(set(want)) > len(want) // 2
 
 
 def _lognormal_matches(spec, values, median_tol):
